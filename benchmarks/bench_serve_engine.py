@@ -51,9 +51,9 @@ ride existing readbacks), ``chaos_injected`` records survival rate
 under a seeded nan/oom/slow/malformed plan with every survivor
 token-checked against the fault-free run, and ``chaos_crash`` kills
 the engine mid-trace and records the journal-restart recovery latency.
-A ``--mesh`` sweep benches sharded serving under a forced 4-device host
-mesh (2x2 data x model, dense and paged) against single-device on the
-same trace, asserting token-exactness and that ``host_syncs_per_token``
+A ``--mesh`` sweep benches sharded serving on 4 visible devices
+(2x2 data x model, dense and paged) against single-device on the same
+trace, asserting token-exactness and that ``host_syncs_per_token``
 does not regress; every JSON entry records ``mesh_shape``/``n_devices``
 (pre-sharding entries backfill as 1x1 so the schema stays uniform).
 Partial runs (``--family``, ``--speculate``, ``--pool``, ``--chaos``,
@@ -367,15 +367,15 @@ def _bench_speculative(quick: bool):
     return results
 
 
-def _bench_kernel_modes(quick: bool):
+def _bench_kernel_modes(quick: bool, kernel_mode: str):
     """Kernel-vs-jnp slot decode, side by side, full-KV and ring-window.
 
     Same trace, same K, only ``cfg.decode_kernel`` differs — the entry
-    pair is the direct measure of the kernel-backed slot path.  On this
-    CPU container the kernel modes run the Pallas INTERPRETER (orders of
-    magnitude slower than compiled — the entries document correctness
-    cost, not TPU speed; on a TPU backend ``auto`` compiles).  The trace
-    is kept small accordingly.
+    pair is the direct measure of the kernel-backed slot path.
+    ``kernel_mode`` is ``"auto"`` (compiled Pallas, TPU only) or
+    ``"interpret"`` (the Pallas INTERPRETER, orders of magnitude slower
+    than compiled — such entries document correctness cost, not TPU
+    speed).  The trace is kept small for the interpreter's sake.
     """
     cfg = get_config(FAMILY_ARCHS["transformer"])
     fam = get_family(cfg)
@@ -390,7 +390,6 @@ def _bench_kernel_modes(quick: bool):
                 for r in reqs]
 
     results = {}
-    kernel_mode = "auto" if jax.default_backend() == "tpu" else "interpret"
     for tag, cfg_m in (("jnp", cfg),
                        (kernel_mode,
                         cfg.replace(decode_kernel=kernel_mode))):
@@ -627,107 +626,85 @@ def _bench_chaos(quick: bool):
 def _bench_mesh(quick: bool):
     """Sharded-vs-single-device serving on one deterministic trace.
 
-    The in-process jax sees 1 CPU device, so the sweep runs in a
-    subprocess with 4 forced host devices: the same gpt-micro trace
-    through the single-device engine, a 2x2 (data x model) dense engine,
-    and a 2x2 paged engine.  The subprocess ASSERTS the acceptance
-    criteria before reporting — sharded tokens must equal single-device
-    tokens exactly, and sharded ``host_syncs_per_token`` must not exceed
-    single-device on the same trace (the readback-locality contract:
-    sharding adds collectives on device, never host syncs) — so a
-    regression fails the bench rather than drifting into the trajectory.
-    Entries record ``mesh_shape``/``n_devices``; forced host devices
-    measure dispatch structure, not real multi-chip speed.
+    Runs in this process over the visible devices, which must number 4:
+    the same gpt-micro trace through the single-device engine, a 2x2
+    (data x model) dense engine, and a 2x2 paged engine.  The sweep
+    ASSERTS the acceptance criteria before reporting — sharded tokens
+    must equal single-device tokens exactly, and sharded
+    ``host_syncs_per_token`` must not exceed single-device on the same
+    trace (the readback-locality contract: sharding adds collectives on
+    device, never host syncs) — so a regression fails the bench rather
+    than drifting into the trajectory.  Entries record
+    ``mesh_shape``/``n_devices``.  On a CPU host, start the process with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``; forced host
+    devices measure dispatch structure, not multi-chip speed.
     """
-    import json as _json
-    import os
-    import pathlib
-    import subprocess
-    import sys
-    import textwrap
-
+    if len(jax.devices()) != 4:
+        raise RuntimeError(
+            f"the mesh sweep needs 4 visible devices, found "
+            f"{len(jax.devices())} (on a CPU host start the process with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=4)")
     n = 8 if quick else 24
     gen = 8 if quick else 16
-    child = textwrap.dedent(f"""
-        import json, time
-        import jax
-        import numpy as np
-        from repro.configs.base import get_config
-        from repro.models import get_family, slot_cache_layout
-        from repro.serve import ContinuousBatchingEngine, Request
-        from benchmarks.bench_serve_engine import poisson_trace
+    cfg = get_config("gpt-micro")
+    params = get_family(cfg).init(jax.random.PRNGKey(0), cfg)
+    reqs = poisson_trace(cfg, n, rate_hz=2000.0, max_gen=gen)
 
-        cfg = get_config("gpt-micro")
-        params = get_family(cfg).init(jax.random.PRNGKey(0), cfg)
-        reqs = poisson_trace(cfg, {n}, rate_hz=2000.0, max_gen={gen})
+    def fresh():
+        return [Request(uid=r.uid, prompt=r.prompt,
+                        max_new_tokens=r.max_new_tokens,
+                        arrival=r.arrival) for r in reqs]
 
-        def fresh():
-            return [Request(uid=r.uid, prompt=r.prompt,
-                            max_new_tokens=r.max_new_tokens,
-                            arrival=r.arrival) for r in reqs]
+    def bench(mesh, pool):
+        def build():
+            return ContinuousBatchingEngine(
+                cfg, params, capacity=4, max_len=48, k=8, pool=pool,
+                mesh=mesh)
+        build().run(fresh())          # warm the shared jit caches
+        eng = build()
+        t0 = time.monotonic()
+        out = eng.run(fresh())        # realtime=False: deterministic
+        dt = time.monotonic() - t0
+        n_tok = sum(len(v) for v in out.values())
+        m = {
+            "tok_per_s": n_tok / dt, "p50_s": 0.0, "p99_s": 0.0,
+            "host_syncs_per_token": eng.n_host_syncs / max(n_tok, 1),
+            "decode_dispatches": eng.n_decode_dispatches,
+            "prefill_batches": eng.n_prefills, "k": 8,
+            "pool": eng.pool_kind, "mesh_shape": eng.mesh_shape,
+            "n_devices": eng.n_devices, "family": cfg.family,
+            "cache_layout": slot_cache_layout(cfg),
+            "params_bytes_per_device": eng.params_bytes_per_device,
+            "pool_bytes_per_device": eng.pool_bytes_per_device,
+        }
+        if eng.pool_kind == "paged":
+            m["pages_highwater"] = eng.pages_highwater
+            m["prefix_hit_rate"] = eng.prefix_hit_rate
+            m["pages_per_request"] = (eng.n_pages_allocated
+                                      / max(len(reqs), 1))
+            m["dense_reservation_pages"] = eng._metas[0].nblk
+            m["rejected"] = len(eng.rejected)
+        return out, m
 
-        def bench(mesh, pool):
-            def build():
-                return ContinuousBatchingEngine(
-                    cfg, params, capacity=4, max_len=48, k=8, pool=pool,
-                    mesh=mesh)
-            build().run(fresh())          # warm the shared jit caches
-            eng = build()
-            t0 = time.monotonic()
-            out = eng.run(fresh())        # realtime=False: deterministic
-            dt = time.monotonic() - t0
-            n_tok = sum(len(v) for v in out.values())
-            m = {{
-                "tok_per_s": n_tok / dt, "p50_s": 0.0, "p99_s": 0.0,
-                "host_syncs_per_token": eng.n_host_syncs / max(n_tok, 1),
-                "decode_dispatches": eng.n_decode_dispatches,
-                "prefill_batches": eng.n_prefills, "k": 8,
-                "pool": eng.pool_kind, "mesh_shape": eng.mesh_shape,
-                "n_devices": eng.n_devices, "family": cfg.family,
-                "cache_layout": slot_cache_layout(cfg),
-                "params_bytes_per_device": eng.params_bytes_per_device,
-                "pool_bytes_per_device": eng.pool_bytes_per_device,
-            }}
-            if eng.pool_kind == "paged":
-                m["pages_highwater"] = eng.pages_highwater
-                m["prefix_hit_rate"] = eng.prefix_hit_rate
-                m["pages_per_request"] = (eng.n_pages_allocated
-                                          / max(len(reqs), 1))
-                m["dense_reservation_pages"] = eng._metas[0].nblk
-                m["rejected"] = len(eng.rejected)
-            return eng, out, m
-
-        results = {{}}
-        _, want, results["mesh_1x1_dense_k8"] = bench(None, "dense")
-        for tag, pool in (("mesh_2x2_dense_k8", "dense"),
-                          ("mesh_2x2_paged_k8", "paged")):
-            _, got, results[tag] = bench("2x2", pool)
-            for u in want:
-                assert np.array_equal(got[u], want[u]), \\
-                    (tag, u, got[u], want[u])
-            single = results["mesh_1x1_dense_k8"]["host_syncs_per_token"]
-            shard = results[tag]["host_syncs_per_token"]
-            assert shard <= single + 1e-9, (tag, shard, single)
-        print("BENCH_JSON:" + json.dumps(results))
-    """)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    root = pathlib.Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = f"{root / 'src'}:{root}"
-    out = subprocess.run([sys.executable, "-c", child],
-                         capture_output=True, text=True, env=env,
-                         timeout=560)
-    if out.returncode != 0:
-        raise RuntimeError("mesh bench subprocess failed:\n"
-                           + out.stderr[-3000:])
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON:")][-1]
-    return _json.loads(line[len("BENCH_JSON:"):])
+    results = {}
+    want, results["mesh_1x1_dense_k8"] = bench(None, "dense")
+    for tag, pool in (("mesh_2x2_dense_k8", "dense"),
+                      ("mesh_2x2_paged_k8", "paged")):
+        got, results[tag] = bench("2x2", pool)
+        for u in want:
+            assert np.array_equal(got[u], want[u]), \
+                (tag, u, got[u], want[u])
+        single = results["mesh_1x1_dense_k8"]["host_syncs_per_token"]
+        shard = results[tag]["host_syncs_per_token"]
+        assert shard <= single + 1e-9, (tag, shard, single)
+    return results
 
 
 def run(quick: bool = False, write_json: bool = True, families=None,
-        speculate: bool = False, kernel: bool = False, pool: bool = False,
+        speculate: bool = False, kernel=None, pool: bool = False,
         chaos: bool = False, mesh: bool = False):
+    """``kernel``: ``None`` skips the kernel-vs-jnp section, else the
+    Pallas mode it runs (``"auto"`` or ``"interpret"``)."""
     families = tuple(FAMILY_ARCHS) if families is None else tuple(families)
     results = {}
     partial = set(families) != set(FAMILY_ARCHS) or speculate or kernel \
@@ -752,7 +729,7 @@ def run(quick: bool = False, write_json: bool = True, families=None,
         # would accumulate stale side-by-side entries per layout
         for key in [k for k in results if k.startswith("kernel_")]:
             del results[key]
-        results.update(_bench_kernel_modes(quick))
+        results.update(_bench_kernel_modes(quick, kernel))
     if pool:
         # like the kernel section: the dense-vs-paged pairs always
         # reflect THIS sweep — purge merged-in pool_* keys first
@@ -810,9 +787,10 @@ if __name__ == "__main__":
     ap.add_argument("--speculate", action="store_true",
                     help="also bench speculative decode on the grown "
                          "gpt-micro pair (acceptance_rate recorded)")
-    ap.add_argument("--kernel", action="store_true",
+    ap.add_argument("--kernel", default=None, choices=("auto", "interpret"),
                     help="also bench kernel-vs-jnp slot decode side by "
-                         "side (Pallas interpreter off-TPU — small trace)")
+                         "side in this Pallas mode ('auto' compiles and "
+                         "needs a TPU; 'interpret' — small trace)")
     ap.add_argument("--pool", action="store_true",
                     help="also bench dense-vs-paged slot pool pairs per "
                          "family (transformer/griffin/xlstm) plus ring "
@@ -824,9 +802,11 @@ if __name__ == "__main__":
                          "(survivors token-checked), and crash+journal "
                          "recovery latency")
     ap.add_argument("--mesh", action="store_true",
-                    help="also bench sharded serving on a forced 4-device "
-                         "host mesh (2x2 dense + paged vs single-device; "
-                         "token-exactness and host-sync parity asserted)")
+                    help="also bench sharded serving on 4 visible devices "
+                         "(2x2 dense + paged vs single-device; "
+                         "token-exactness and host-sync parity asserted; "
+                         "on CPU set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=4)")
     a = ap.parse_args()
     fams = {"all": tuple(FAMILY_ARCHS), "none": ()}.get(
         a.family, (a.family,))

@@ -17,7 +17,12 @@ def main() -> None:
                     help="comma list: table1,fig6,fig7,transfer,roofline,"
                          "kernels,serve,spec,servek,servep,servec,servem,"
                          "serveg")
+    ap.add_argument("--kernel", default="auto", choices=("auto", "interpret"),
+                    help="Pallas mode of servek and the serveg kernel cell "
+                         "('auto' compiles and needs a TPU)")
     args, _ = ap.parse_known_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")
@@ -45,7 +50,7 @@ def main() -> None:
     if section("servek"):
         # kernel-vs-jnp slot decode only (merges into the serve JSON)
         from benchmarks.bench_serve_engine import run as sv_kern
-        sv_kern(quick=args.quick, families=(), kernel=True)
+        sv_kern(quick=args.quick, families=(), kernel=args.kernel)
     if section("servep"):
         # dense-vs-paged slot pool pairs only (merges into the serve JSON)
         from benchmarks.bench_serve_engine import run as sv_pool
@@ -55,16 +60,15 @@ def main() -> None:
         from benchmarks.bench_serve_engine import run as sv_chaos
         sv_chaos(quick=args.quick, families=(), chaos=True)
     if section("servem"):
-        # sharded-vs-single-device mesh sweep only (subprocess with 4
-        # forced host devices; merges into the serve JSON)
+        # sharded-vs-single-device mesh sweep only (needs 4 visible
+        # devices; merges into the serve JSON)
         from benchmarks.bench_serve_engine import run as sv_mesh
         sv_mesh(quick=args.quick, families=(), mesh=True)
     if section("serveg"):
-        # scenario sweep: families x pool x kernel x trace-shape matrix
-        # in per-cell subprocesses, incl. mid-trace live-upgrade cells
-        # (merges into the serve JSON)
+        # scenario sweep: families x pool x kernel x trace-shape matrix,
+        # incl. mid-trace live-upgrade cells (merges into the serve JSON)
         from benchmarks.scenarios import run as sv_scen
-        sv_scen(quick=args.quick)
+        sv_scen(quick=args.quick, kernel=args.kernel)
     if section("fig6"):
         from benchmarks.bench_fig6_rank_ablation import run as f6
         f6(quick=args.quick)

@@ -1,13 +1,13 @@
 """Scenario sweep harness: families x pool x kernel x trace-shape matrix,
-one subprocess per cell, merged into ``BENCH_serve_engine.json``.
+merged into ``BENCH_serve_engine.json``.
 
-Each cell runs in its OWN interpreter so jit caches, page arenas and
-window counters never bleed between configurations — the numbers are
-what a cold engine of that exact shape does on that exact trace.  The
-child prints a single ``BENCH_JSON:{...}`` line (the same protocol as
-``bench_serve_engine._bench_mesh``); the parent collects the cells,
-purges stale ``scenario_*`` / ``upgrade_*`` keys, and MERGES into the
-serve JSON so the other sections' trajectory entries survive.
+Every cell runs in the calling process: a process that has touched JAX
+holds the accelerator, so a child started from here could not reach it.
+Each cell builds a cold engine of its exact shape; compiled functions are
+shared between cells of the same configuration (``_jitted_engine_fns`` is
+keyed on frozen configs), so only a cell's first run pays its compiles.
+The sweep purges stale ``scenario_*`` / ``upgrade_*`` keys and MERGES
+into the serve JSON so the other sections' trajectory entries survive.
 
 Trace shapes:
   * ``bursty``      — short mixed requests arriving in two dense waves
@@ -26,22 +26,29 @@ resumed count, pre/post-swap tok/s, and the post-swap speculative
 acceptance rate.
 
 Run:  PYTHONPATH=src:. python benchmarks/scenarios.py [--quick]
+          [--kernel auto|interpret]
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
-import subprocess
-import sys
+import time
+
+import jax
+import numpy as np
 
 from benchmarks.common import write_bench_json
+from repro.configs.base import get_config
+from repro.data.synthetic import lm_batch
+from repro.launch.serve import generate
+from repro.models import get_family, slot_cache_layout
+from repro.serve import ContinuousBatchingEngine, Request, UpgradeManager
 
 # quick=True cells form the CI smoke subset; the rest only run in the
-# full sweep.  The interpret-kernel cell runs the Pallas INTERPRETER on
-# CPU hosts (documenting correctness cost, not TPU speed) and is kept
-# tiny for that reason.
+# full sweep.  The kernel cell runs the Pallas mode the sweep is given;
+# under ``"interpret"`` (CPU hosts: correctness cost, not TPU speed) its
+# trace is kept tiny.
 SCENARIOS = (
     {"key": "scenario_gpt_dense_bursty", "arch": "gpt-micro",
      "pool": "dense", "trace": "bursty", "quick": True},
@@ -69,148 +76,117 @@ SCENARIOS = (
      "upgrade": True, "quick": False},
 )
 
-# the child re-reads its cell spec from argv[1]; everything it needs is
-# in-repo, so the only environment is PYTHONPATH
-_CHILD = r'''
-import json, sys, time
-import jax
-import numpy as np
-from repro.configs.base import get_config
-from repro.data.synthetic import lm_batch
-from repro.launch.serve import generate
-from repro.models import get_family, slot_cache_layout
-from repro.serve import ContinuousBatchingEngine, Request, UpgradeManager
-
-spec = json.loads(sys.argv[1])
-quick = spec["quick_run"]
-cfg = get_config(spec["arch"])
-if spec.get("kernel") == "kernel":
-    mode = "auto" if jax.default_backend() == "tpu" else "interpret"
-    cfg = cfg.replace(decode_kernel=mode)
-params = get_family(cfg).init(jax.random.PRNGKey(0), cfg)
-
 MAX_LEN = 40
-capacity, k = 3, 2
-interp = cfg.decode_kernel not in ("jnp", "auto") \
-    and jax.default_backend() != "tpu"
 
 
-def _req(uid, plen, gen, arrival=0.0, eos=None):
-    prompt = lm_batch(cfg.vocab_size, 1, plen, seed=400 + uid)[0]
-    return Request(uid=uid, prompt=prompt, max_new_tokens=gen,
-                   arrival=arrival, eos_id=eos)
+def run_cell(spec, quick, kernel):
+    """Serve one cell's trace on a fresh engine; returns {key: metrics}.
+    ``kernel`` is the Pallas mode of the kernel cell: ``"auto"`` (compiled,
+    TPU only) or ``"interpret"``."""
+    cfg = get_config(spec["arch"])
+    if spec.get("kernel") == "kernel":
+        cfg = cfg.replace(decode_kernel=kernel)
+    params = get_family(cfg).init(jax.random.PRNGKey(0), cfg)
+    capacity, k = 3, 2
+    interp = cfg.decode_kernel == "interpret"
+
+    def _req(uid, plen, gen, arrival=0.0, eos=None):
+        prompt = lm_batch(cfg.vocab_size, 1, plen, seed=400 + uid)[0]
+        return Request(uid=uid, prompt=prompt, max_new_tokens=gen,
+                       arrival=arrival, eos_id=eos)
+
+    def make_trace(kind):
+        rng = np.random.default_rng(7)
+        if kind == "bursty":
+            n = 4 if interp else (8 if quick else 12)
+            g = 4 if interp else 10
+            return [_req(u, int(rng.integers(4, 11)), g,
+                         arrival=0.0 if u < n // 2 else 0.05)
+                    for u in range(n)]
+        if kind == "long_prompt":
+            n = 3 if quick else 5
+            gen = 6
+            return [_req(u, MAX_LEN - gen - int(rng.integers(0, 4)), gen)
+                    for u in range(n)]
+        if kind == "eos_heavy":
+            n = 6 if quick else 10
+            reqs = [_req(u, int(rng.integers(4, 11)), 12) for u in range(n)]
+            out = []
+            for r in reqs:
+                toks = np.asarray(generate(
+                    cfg, params, np.asarray(r.prompt)[None],
+                    max_new_tokens=r.max_new_tokens, max_len=MAX_LEN))[0]
+                # the token it WILL greedily emit mid-budget becomes its eos
+                out.append(Request(uid=r.uid, prompt=r.prompt,
+                                   max_new_tokens=r.max_new_tokens,
+                                   eos_id=int(toks[len(toks) // 2])))
+            return out
+        raise ValueError(kind)
+
+    reqs = make_trace(spec["trace"])
+    eng = ContinuousBatchingEngine(cfg, params, capacity=capacity,
+                                   max_len=MAX_LEN, k=k, pool=spec["pool"],
+                                   prefill_bucket=16)
+    mgr = None
+    if spec.get("upgrade"):
+        mgr = UpgradeManager(eng, get_config(spec["grow"]), upgrade_at=4,
+                             prewarm=not quick)
+        mgr.start(background=False)  # growth pre-done: swap point is exact
+
+    t0 = time.monotonic()
+    out = eng.run(reqs)
+    dt = time.monotonic() - t0
+    n_tok = sum(len(v) for v in out.values())
+    lat = sorted(s.t_done - t0 for s in eng.retired)
+    p50 = lat[len(lat) // 2] if lat else 0.0
+    p99 = lat[min(len(lat) - 1, int(0.99 * len(lat)))] if lat else 0.0
+
+    m = {
+        "tok_per_s": n_tok / dt, "p50_s": p50, "p99_s": p99,
+        "host_syncs_per_token": eng.n_host_syncs / max(n_tok, 1),
+        "k": k, "trace": spec["trace"], "n_requests": len(reqs),
+        "pool": eng.pool_kind, "decode_kernel": eng.decode_kernel,
+        "family": cfg.family, "cache_layout": slot_cache_layout(eng.cfg),
+        "mesh_shape": eng.mesh_shape, "n_devices": eng.n_devices,
+    }
+    if eng.pool_kind == "paged":
+        m["pages_highwater"] = eng.pages_highwater
+        m["prefix_hit_rate"] = eng.prefix_hit_rate
+    if mgr is not None:
+        assert mgr.state == "swapped", mgr.state
+        dropped = len(eng.rejected)
+        assert dropped == 0, eng.rejected  # zero-drop is the contract
+        assert all(v == "finished" for v in eng.outcomes.values()), \
+            eng.outcomes
+        totals = eng.lifetime_totals()
+        pre_tok = mgr.tokens_at_swap
+        m.update({
+            "upgrade_pause_ms": mgr.pause_ms,
+            "grow_s": mgr.grow_seconds,
+            "dropped": dropped,
+            "resumed_requests": mgr.resumed,
+            "held_submits": totals["n_held_for_upgrade"],
+            "pre_swap_tok_per_s": pre_tok / max(mgr.t_swap - t0, 1e-9),
+            "post_swap_tok_per_s": (totals["n_tokens"] - pre_tok)
+                                   / max(t0 + dt - mgr.t_swap, 1e-9),
+            "source": spec["arch"], "target": spec["grow"],
+            # page-residency delta: pages live at quiesce (all invalidated
+            # by the grown params), pages carried (structurally 0), and the
+            # re-prefill page bill the resume wave pays for zero drops
+            "pages_resident_at_swap": mgr.pages_resident_at_swap,
+            "pages_carried": mgr.pages_carried,
+            "pages_reprefilled": mgr.pages_reprefilled,
+        })
+        if eng.speculative is not None:
+            m["acceptance_rate"] = eng.acceptance_rate
+            m["draft"] = eng.speculative.cfg.name
+        elif mgr.spec_reason:
+            m["spec_disabled"] = mgr.spec_reason
+    return {spec["key"]: m}
 
 
-def make_trace(kind):
-    rng = np.random.default_rng(7)
-    if kind == "bursty":
-        n = 4 if interp else (8 if quick else 12)
-        g = 4 if interp else 10
-        return [_req(u, int(rng.integers(4, 11)), g,
-                     arrival=0.0 if u < n // 2 else 0.05)
-                for u in range(n)]
-    if kind == "long_prompt":
-        n = 3 if quick else 5
-        gen = 6
-        return [_req(u, MAX_LEN - gen - int(rng.integers(0, 4)), gen)
-                for u in range(n)]
-    if kind == "eos_heavy":
-        n = 6 if quick else 10
-        reqs = [_req(u, int(rng.integers(4, 11)), 12) for u in range(n)]
-        out = []
-        for r in reqs:
-            toks = np.asarray(generate(
-                cfg, params, np.asarray(r.prompt)[None],
-                max_new_tokens=r.max_new_tokens, max_len=MAX_LEN))[0]
-            # the token it WILL greedily emit mid-budget becomes its eos
-            out.append(Request(uid=r.uid, prompt=r.prompt,
-                               max_new_tokens=r.max_new_tokens,
-                               eos_id=int(toks[len(toks) // 2])))
-        return out
-    raise ValueError(kind)
-
-
-reqs = make_trace(spec["trace"])
-eng = ContinuousBatchingEngine(cfg, params, capacity=capacity,
-                               max_len=MAX_LEN, k=k, pool=spec["pool"],
-                               prefill_bucket=16)
-mgr = None
-if spec.get("upgrade"):
-    mgr = UpgradeManager(eng, get_config(spec["grow"]), upgrade_at=4,
-                         prewarm=not quick)
-    mgr.start(background=False)  # growth pre-done: swap point is exact
-
-t0 = time.monotonic()
-out = eng.run(reqs)
-dt = time.monotonic() - t0
-n_tok = sum(len(v) for v in out.values())
-lat = sorted(s.t_done - t0 for s in eng.retired)
-p50 = lat[len(lat) // 2] if lat else 0.0
-p99 = lat[min(len(lat) - 1, int(0.99 * len(lat)))] if lat else 0.0
-
-m = {
-    "tok_per_s": n_tok / dt, "p50_s": p50, "p99_s": p99,
-    "host_syncs_per_token": eng.n_host_syncs / max(n_tok, 1),
-    "k": k, "trace": spec["trace"], "n_requests": len(reqs),
-    "pool": eng.pool_kind, "decode_kernel": eng.decode_kernel,
-    "family": cfg.family, "cache_layout": slot_cache_layout(eng.cfg),
-    "mesh_shape": eng.mesh_shape, "n_devices": eng.n_devices,
-}
-if eng.pool_kind == "paged":
-    m["pages_highwater"] = eng.pages_highwater
-    m["prefix_hit_rate"] = eng.prefix_hit_rate
-if mgr is not None:
-    assert mgr.state == "swapped", mgr.state
-    dropped = len(eng.rejected)
-    assert dropped == 0, eng.rejected  # zero-drop is the contract
-    assert all(v == "finished" for v in eng.outcomes.values()), \
-        eng.outcomes
-    totals = eng.lifetime_totals()
-    pre_tok = mgr.tokens_at_swap
-    m.update({
-        "upgrade_pause_ms": mgr.pause_ms,
-        "grow_s": mgr.grow_seconds,
-        "dropped": dropped,
-        "resumed_requests": mgr.resumed,
-        "held_submits": totals["n_held_for_upgrade"],
-        "pre_swap_tok_per_s": pre_tok / max(mgr.t_swap - t0, 1e-9),
-        "post_swap_tok_per_s": (totals["n_tokens"] - pre_tok)
-                               / max(t0 + dt - mgr.t_swap, 1e-9),
-        "source": spec["arch"], "target": spec["grow"],
-        # page-residency delta: pages live at quiesce (all invalidated by
-        # the grown params), pages carried (structurally 0), and the
-        # re-prefill page bill the resume wave pays for zero drops
-        "pages_resident_at_swap": mgr.pages_resident_at_swap,
-        "pages_carried": mgr.pages_carried,
-        "pages_reprefilled": mgr.pages_reprefilled,
-    })
-    if eng.speculative is not None:
-        m["acceptance_rate"] = eng.acceptance_rate
-        m["draft"] = eng.speculative.cfg.name
-    elif mgr.spec_reason:
-        m["spec_disabled"] = mgr.spec_reason
-print("BENCH_JSON:" + json.dumps({spec["key"]: m}))
-'''
-
-
-def _run_cell(spec, quick, timeout=560):
-    root = pathlib.Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"{root / 'src'}:{root}"
-    payload = dict(spec, quick_run=quick)
-    out = subprocess.run([sys.executable, "-c", _CHILD,
-                          json.dumps(payload)],
-                         capture_output=True, text=True, env=env,
-                         timeout=timeout)
-    if out.returncode != 0:
-        raise RuntimeError(f"scenario cell {spec['key']} failed:\n"
-                           + out.stderr[-3000:])
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON:")][-1]
-    return json.loads(line[len("BENCH_JSON:"):])
-
-
-def run(quick: bool = False, write_json: bool = True):
+def run(quick: bool = False, write_json: bool = True,
+        kernel: str = "auto"):
     cells = [s for s in SCENARIOS if s["quick"] or not quick]
     results = {}
     if write_json:
@@ -223,7 +199,7 @@ def run(quick: bool = False, write_json: bool = True):
                     if k.startswith(("scenario_", "upgrade_"))]:
             del results[key]
     for spec in cells:
-        results.update(_run_cell(spec, quick))
+        results.update(run_cell(spec, quick, kernel))
     for name in (s["key"] for s in cells):
         m = results[name]
         print(f"serve_{name},tok_per_s,{m['tok_per_s']:.1f}")
@@ -254,5 +230,8 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--no-json", action="store_true")
+    ap.add_argument("--kernel", default="auto", choices=("auto", "interpret"),
+                    help="Pallas mode of the kernel cell ('auto' compiles "
+                         "and needs a TPU)")
     a = ap.parse_args()
-    run(quick=a.quick, write_json=not a.no_json)
+    run(quick=a.quick, write_json=not a.no_json, kernel=a.kernel)

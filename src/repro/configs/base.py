@@ -94,10 +94,11 @@ class ModelConfig:
     attn_prefix_chunks: bool = False  # static-prefix causal chunks (§Perf)
     unroll_scans: bool = False  # unroll inner chunk scans (cost calibration)
     # serving slot-decode attention backend: "jnp" (pure-jnp model path),
-    # "auto" (Pallas kernels — compiled on TPU, interpreter elsewhere),
-    # "interpret" (Pallas CPU interpreter), "reference" (kernels/ref.py
-    # oracles).  Non-jnp modes route decode_step_slots / verify_step_slots
-    # through kernels/ops.py; MLA latent caches always use the jnp path.
+    # "auto" (compiled Pallas kernels; a TPU backend is required — off a
+    # TPU it raises kernels.ops.NoTPUError), "interpret" (the Pallas
+    # interpreter, any backend), "reference" (kernels/ref.py oracles).
+    # Non-jnp modes route decode_step_slots / verify_step_slots through
+    # kernels/ops.py; MLA latent caches always use the jnp path.
     decode_kernel: str = "jnp"
 
     def __post_init__(self):

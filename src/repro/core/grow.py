@@ -127,8 +127,10 @@ def train_operator(gop: GrowthOperator, op_params, params_src, loss_fn,
         return op_params, []
     from repro.optim import adamw_init, adamw_update
 
-    def objective(p, batch):
-        big = grow_params(gop, p, params_src)
+    # the source weights are an argument, not a closure: closed over,
+    # they would be baked into the program as constants
+    def objective(p, src, batch):
+        big = grow_params(gop, p, src)
         return loss_fn(big, batch)
 
     opt_state = adamw_init(op_params)
@@ -141,7 +143,7 @@ def train_operator(gop: GrowthOperator, op_params, params_src, loss_fn,
     losses = []
     for step in range(steps):
         batch = next(data_iter)
-        loss, grads = grad_fn(op_params, batch)
+        loss, grads = grad_fn(op_params, params_src, batch)
         op_params, opt_state = upd(op_params, opt_state, grads,
                                    jnp.int32(step + 1))
         losses.append(float(loss))

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map_compat
-
 
 def bf16_compress(grads):
     """Lossy cast hook (applied pre-optimizer, after the mean)."""
@@ -58,9 +56,9 @@ def make_crosspod_psum(mesh, *, method: str = "bf16", axis: str = "pod"):
                                 axis).astype(g.dtype) / n
 
         def psum_fn(grads):
-            fn = shard_map_compat(
-                lambda t: jax.tree.map(inner, t), mesh,
-                in_specs=P(), out_specs=P())
+            fn = jax.shard_map(
+                lambda t: jax.tree.map(inner, t), mesh=mesh,
+                in_specs=P(), out_specs=P(), check_vma=False)
             return fn(grads)
         return psum_fn
 
@@ -82,9 +80,9 @@ def make_crosspod_psum(mesh, *, method: str = "bf16", axis: str = "pod"):
                 e_new = jax.tree.map(lambda t: t[1], out,
                                      is_leaf=lambda t: isinstance(t, tuple))
                 return g_new, e_new
-            fn = shard_map_compat(
-                mapped, mesh, in_specs=(P(), P()),
-                out_specs=(P(), P()))
+            fn = jax.shard_map(
+                mapped, mesh=mesh, in_specs=(P(), P()),
+                out_specs=(P(), P()), check_vma=False)
             return fn(grads, err)
         return psum_fn
 

@@ -18,10 +18,10 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.distributed.sharding import params_shardings, \
     sharding_rules_for_mesh
-from repro.utils.compat import make_mesh_compat
 
 
 def choose_mesh_shape(n_devices: int, prefer_model: int = 16):
@@ -37,7 +37,8 @@ def choose_mesh_shape(n_devices: int, prefer_model: int = 16):
 def make_elastic_mesh(prefer_model: int = 16):
     n = len(jax.devices())
     shape = choose_mesh_shape(n, prefer_model)
-    return make_mesh_compat(shape, ("data", "model"))
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def reshard_restore(ckpt_dir: str, template, param_specs, *,

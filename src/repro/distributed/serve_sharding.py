@@ -43,7 +43,7 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import (
     LOGICAL_RULES_SINGLE_POD,
@@ -52,7 +52,6 @@ from repro.distributed.sharding import (
     params_shardings,
     use_rules,
 )
-from repro.utils.compat import make_mesh_compat
 
 
 def serve_sharding_rules() -> dict:
@@ -143,7 +142,8 @@ class ServeMeshPlan:
         self.shape = shape
         self.data, self.model = shape
         self.n_devices = self.data * self.model
-        self.mesh = make_mesh_compat(shape, ("data", "model"))
+        self.mesh = jax.make_mesh(shape, ("data", "model"),
+                                  axis_types=(AxisType.Auto,) * 2)
         self.rules = serve_sharding_rules()
 
     def describe(self) -> str:

@@ -20,8 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.compat import get_abstract_mesh
-
 # logical axis -> physical mesh axis (or tuple of axes)
 LOGICAL_RULES_SINGLE_POD = {
     "batch": ("data",),
@@ -118,24 +116,6 @@ def use_rules(mesh: Mesh, rules: dict | None = None):
         _STATE.rules, _STATE.mesh = prev
 
 
-@contextlib.contextmanager
-def suspend_rules():
-    """Deactivate logical-rule annotations (``annotate`` becomes a no-op).
-
-    Old-jax escape hatch for partial-manual ``shard_map`` bodies: a
-    ``with_sharding_constraint`` built on the concrete mesh there trips the
-    SPMD partitioner's manual-subgroup check, and without abstract-mesh
-    introspection ``annotate`` cannot rebuild the constraint correctly —
-    inside such regions GSPMD must infer layouts from the operands alone.
-    """
-    prev = (_STATE.rules, _STATE.mesh)
-    _STATE.rules, _STATE.mesh = None, None
-    try:
-        yield
-    finally:
-        _STATE.rules, _STATE.mesh = prev
-
-
 def _axis_sizes(mesh: Mesh) -> dict:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
@@ -196,9 +176,9 @@ def annotate(x, logical):
         return x
     spec = logical_to_spec(logical, shape=x.shape)
     mesh = _STATE.mesh
-    cur = get_abstract_mesh()
-    if cur is not None and getattr(cur, "_any_axis_manual", False):
-        manual = set(cur.manual_axes)
+    cur = jax.sharding.get_abstract_mesh()
+    manual = set(cur.manual_axes)
+    if manual:
         parts = []
         for e in spec:
             if e is None:

@@ -1,10 +1,12 @@
 """Pallas TPU kernels: flash-decode against a KV cache (slot-serving family).
 
 Decode attention is memory-bound: the whole KV cache streams through VMEM
-once per step.  Grid (B, KV, Sk/BK) with the cache axis innermost; a running
-(m, l, acc) per (batch, kv-head) lives in VMEM scratch — all G query heads
-of a kv group are processed together as a (G, hd) tile so the cache block is
-read exactly once per group (the GQA bandwidth win).
+once per step, with the cache axis innermost in the grid and a running
+(m, l, acc) per (batch, kv-head) in VMEM scratch — all G query heads of a
+kv group are processed together as a (G, hd) tile so the cache block is
+read exactly once per group (the GQA bandwidth win).  The head-major
+kernel grids (B, KV, Sk/BK); the pool-layout kernels grid (B, Sk/BK) over
+blocks that hold every kv head (see the section below).
 
 ``kv_len`` masks the unwritten cache tail (padded caches); it may be a
 scalar (uniform batch) or a (B,) vector — the continuous-batching case
@@ -25,9 +27,10 @@ Four kernels share the streaming-softmax machinery:
                                 row against [cache ‖ chunk] at per-row
                                 offsets, cache read-only.
 
-The slot-path kernels encode done/idle rows as a negative per-row scalar
-(kv_len == 0, slot_positions == -1, offsets == -1): every KV block is
-skipped and the empty accumulator finalizes to exact zeros.
+each with a paged twin that reads a shared page arena through block
+tables.  The slot-path kernels encode done/idle rows as a negative per-row
+scalar (kv_len == 0, slot_positions == -1, offsets == -1): every KV block
+is skipped and the empty accumulator finalizes to exact zeros.
 """
 from __future__ import annotations
 
@@ -39,29 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def _flash_update(s, v, m_ref, l_ref, acc_ref):
-    """One streaming-softmax accumulator update.
-
-    s: (..., BK) masked logits; v: (BK, hd) values; scratch shapes are
-    m/l: (..., 1) and acc: (..., hd).  A block must contain at least one
-    unmasked logit (callers guard with ``mask.any()``) — otherwise the
-    NEG_INF - NEG_INF shift would turn masked entries into exp(0).
-    """
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-
-def _finalize(o_ref, acc_ref, l_ref, idx):
-    o_ref[idx] = (acc_ref[...] /
-                  jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -173,36 +153,73 @@ def decode_attention(q, k, v, kv_len, *, bk=None, interpret=False):
 # ===================================================== pool-layout kernels
 # The serve engine's slot pool stores KV as (B, S, KV, hd) — scatters index
 # the cache axis right after the slot axis.  These kernels read that layout
-# directly (BlockSpec (1, bk, 1, hd) over the cache axis), so the hot path
-# never transposes the pool.
+# directly, so the hot path never transposes the pool.  A cache block spans
+# ALL kv heads, (1, bk, KV, hd): Mosaic tiles the last two block dims, which
+# must be divisible by (8, 128) or equal the array's, so a per-head block
+# (1, bk, 1, hd) over KV > 1 heads does not compile for a TPU.  The grid is
+# (B, cache blocks) and each body walks the heads with a static loop; the
+# running (m, l, acc) scratch carries a leading head axis.
+
+def _flash_update(s, v, m_ref, l_ref, acc_ref, h, valid=None):
+    """One streaming-softmax accumulator update for kv head ``h``.
+
+    s: (R, BK) masked logits; v: (BK, hd) values; scratch rows
+    m/l: (R, 1) and acc: (R, hd) at ``[h]``.  ``valid`` (broadcastable to
+    s) zeroes masked probabilities explicitly, for blocks that may hold
+    no unmasked logit at all — there the NEG_INF - NEG_INF shift would
+    otherwise turn masked entries into exp(0).  A fully-masked block then
+    leaves the accumulator bit-identical (alpha == 1, p == 0).
+    """
+    m_prev = m_ref[h]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if valid is not None:
+        p = jnp.where(valid, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[h] = l_ref[h] * alpha + jnp.sum(p, -1, keepdims=True)
+    acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_ref[h] = m_new
+
+
+def _init_scratch(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _finalize(o_ref, acc_ref, l_ref):
+    o_ref[0] = (acc_ref[...] /
+                jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
 
 def _slot_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                  *, bk, scale):
     """Full-KV slot decode: per-row valid length, pool layout."""
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
+    n_kv, g = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
     kv_len = len_ref[pl.program_id(0)]
 
     @pl.when(ki * bk < kv_len)
     def _body():
-        q = q_ref[0, 0]       # (G, hd)
-        k = k_ref[0, :, 0]    # (BK, hd)
-        v = v_ref[0, :, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        _flash_update(s, v, m_ref, l_ref, acc_ref)
+        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1)
+        valid = kpos < kv_len
+        for h in range(n_kv):
+            q = q_ref[0, h]       # (G, hd)
+            k = k_ref[0, :, h]    # (BK, hd)
+            v = v_ref[0, :, h]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            _flash_update(s, v, m_ref, l_ref, acc_ref, h)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _fin():
-        _finalize(o_ref, acc_ref, l_ref, (0, 0))
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 def _ring_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -217,70 +234,80 @@ def _ring_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     ``slot_positions < 0`` (done/idle) skip every block and finalize to
     exact zeros.
     """
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
+    n_kv, g = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
     qpos = pos_ref[pl.program_id(0)]  # row length - 1 == query position
-    slot = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    wrap = qpos // ring  # == (cur_len - 1) // ring with cur_len = qpos + 1
-    base = wrap * ring + slot
-    kpos = jnp.where(base <= qpos, base, base - ring)
-    valid = (kpos >= 0) & (kpos > qpos - window)  # kpos <= qpos by constr.
 
-    @pl.when((qpos >= 0) & jnp.any(valid))
+    @pl.when(qpos >= 0)
     def _body():
-        q = q_ref[0, 0]       # (G, hd)
-        k = k_ref[0, :, 0]    # (BK, hd)
-        v = v_ref[0, :, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, NEG_INF)
-        _flash_update(s, v, m_ref, l_ref, acc_ref)
+        slot = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1)
+        wrap = qpos // ring  # == (cur_len - 1) // ring, cur_len = qpos + 1
+        base = wrap * ring + slot
+        kpos = jnp.where(base <= qpos, base, base - ring)
+        valid = (kpos >= 0) & (kpos > qpos - window)  # kpos <= qpos
+        for h in range(n_kv):
+            q = q_ref[0, h]       # (G, hd)
+            k = k_ref[0, :, h]    # (BK, hd)
+            v = v_ref[0, :, h]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            _flash_update(s, v, m_ref, l_ref, acc_ref, h, valid)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _fin():
-        _finalize(o_ref, acc_ref, l_ref, (0, 0))
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 def _chunk_kernel(off_ref, q_ref, ck_ref, cv_ref, kc_ref, vc_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, bk, nk, s_chunk, cache_len,
-                  ring, window, scale):
+                  m_ref, l_ref, acc_ref, *, bk, nk, g, cache_len, ring,
+                  window, scale):
     """Speculative chunk-verify: S = d+1 queries per row over
     [cache ‖ chunk] at per-row offsets, cache READ-ONLY.
 
-    Grid axis 2 runs nk cache blocks then one chunk step (j == nk): the
-    cache streams through VMEM exactly once while all S chunk queries
-    accumulate, and the in-flight chunk's own K/V (tiny: S keys) is
-    attended causally in the final step.  ``ring`` selects the ring- vs
-    full-layout reconstruction of cache key positions; rows with
-    ``offsets < 0`` (done) produce exact zeros.
+    Queries arrive head-major with the chunk and group axes flattened,
+    (KV, S * G, hd): row ``r`` is chunk query ``r // G``.  Grid axis 1
+    runs nk cache blocks then one chunk step (j == nk): the cache streams
+    through VMEM exactly once while all S chunk queries accumulate, and
+    the in-flight chunk's own K/V (tiny: S keys) is attended causally in
+    the final step.  ``ring`` selects the ring- vs full-layout
+    reconstruction of cache key positions; rows with ``offsets < 0``
+    (done) produce exact zeros.
     """
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     off = off_ref[pl.program_id(0)]
+    n_kv, rows = q_ref.shape[1], q_ref.shape[2]
+    s_chunk = rows // g
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
-    # query j sits at absolute position off + j  -> (S, 1, 1)
-    qpos = off + jax.lax.broadcasted_iota(jnp.int32, (s_chunk, 1, 1), 0)
-
-    def band(kpos):
+    def band(kpos, width):
+        # query row r sits at absolute position off + r // G
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+        qpos = off + (r if g == 1 else r // g)
         v = (kpos >= 0) & (kpos <= qpos)
         if window is not None:
             v &= kpos > qpos - window
         return v
 
+    def attend(k_rows, v_rows, valid):
+        for h in range(n_kv):
+            q = q_ref[0, h]        # (S*G, hd)
+            k = k_rows(h)          # (W, hd)
+            v = v_rows(h)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            _flash_update(s, v, m_ref, l_ref, acc_ref, h, valid)
+
     @pl.when((off >= 0) & (j < nk))
     def _cache_block():
-        slot = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+        slot = j * bk + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
         if ring:
             # committed length == off: slot s holds the largest p < off
             # with p % ring == s (never-written slots go negative)
@@ -289,35 +316,45 @@ def _chunk_kernel(off_ref, q_ref, ck_ref, cv_ref, kc_ref, vc_ref, o_ref,
             kpos = jnp.where(base < off, base, base - cache_len)
         else:
             kpos = jnp.where(slot < off, slot, -1)
-        valid = band(kpos)
-
-        @pl.when(jnp.any(valid))
-        def _():
-            q = q_ref[0, :, 0]      # (S, G, hd)
-            k = ck_ref[0, :, 0]     # (BK, hd)
-            v = cv_ref[0, :, 0]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)
-            _flash_update(s, v, m_ref, l_ref, acc_ref)
+        attend(lambda h: ck_ref[0, :, h], lambda h: cv_ref[0, :, h],
+               band(kpos, bk))
 
     @pl.when((off >= 0) & (j == nk))
     def _chunk_block():
-        kpos = off + jax.lax.broadcasted_iota(jnp.int32, (1, 1, s_chunk), 2)
-        valid = band(kpos)  # causal within the chunk (first key always in)
-        q = q_ref[0, :, 0]      # (S, G, hd)
-        k = kc_ref[0, :, 0]     # (S, hd)
-        v = vc_ref[0, :, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, NEG_INF)
-        _flash_update(s, v, m_ref, l_ref, acc_ref)
+        kpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, s_chunk), 1)
+        # causal within the chunk (a row's own key is always in)
+        attend(lambda h: kc_ref[0, :, h], lambda h: vc_ref[0, :, h],
+               band(kpos, s_chunk))
 
     @pl.when(j == nk)
     def _fin():
-        _finalize(o_ref, acc_ref, l_ref, (0, slice(None), 0))
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 def _scalar_rows(x, B):
     return jnp.broadcast_to(jnp.asarray(x, jnp.int32).reshape(-1), (B,))
+
+
+def _decode_scratch(KV, g, hd):
+    return [pltpu.VMEM((KV, g, 1), jnp.float32),
+            pltpu.VMEM((KV, g, 1), jnp.float32),
+            pltpu.VMEM((KV, g, hd), jnp.float32)]
+
+
+def _chunk_q(q, KV):
+    """(B, S, H, hd) chunk queries -> head-major (B, KV, S * G, hd)."""
+    B, S, H, hd = q.shape
+    g = H // KV
+    return (q.reshape(B, S, KV, g, hd).transpose(0, 2, 1, 3, 4)
+            .reshape(B, KV, S * g, hd))
+
+
+def _chunk_out(o, S):
+    """Inverse of ``_chunk_q`` on the kernel output."""
+    B, KV, rows, hd = o.shape
+    g = rows // S
+    return (o.reshape(B, KV, S, g, hd).transpose(0, 2, 1, 3, 4)
+            .reshape(B, S, KV * g, hd))
 
 
 # ====================================================== paged-pool kernels
@@ -354,12 +391,74 @@ def _paged_chunk_kernel(off_ref, bt_ref, q_ref, ck_ref, cv_ref, kc_ref,
 
 def _page_index_map(n_pages, nblk):
     """Cache-operand index_map: logical block j -> physical page bt[b, j]
-    (clamped sentinel), block offset 0 on the page axis."""
-    def index_map(b, h, j, scal_ref, bt_ref):
+    (sentinels clamped to the last page; the chunk-verify grid's final
+    step overruns to j == nblk and clamps to the last logical block)."""
+    def index_map(b, j, scal_ref, bt_ref):
         del scal_ref
-        jj = jnp.minimum(j, nblk - 1)  # chunk grid overruns clamp (no-op
-        return (jnp.minimum(bt_ref[b, jj], n_pages - 1), 0, h, 0)  # else)
+        jj = jnp.minimum(j, nblk - 1)
+        return (jnp.minimum(bt_ref[b, jj], n_pages - 1), 0, 0, 0)
     return index_map
+
+
+def _row_map(b, j, *_):
+    return (b, 0, 0, 0)
+
+
+def _decode_call(kernel, scalars, bt, q, k, v, *, bk, nblk, index_map,
+                 interpret):
+    """One pool-layout decode ``pallas_call``: q (B, H, hd) against
+    (·, bk, KV, hd) cache blocks fetched by ``index_map`` over a
+    (B, nblk) grid; ``bt`` (paged) rides as a second scalar prefetch."""
+    B, H, hd = q.shape
+    KV = k.shape[2]
+    g = H // KV
+    prefetch = (scalars,) if bt is None else (scalars, bt.astype(jnp.int32))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B, nblk),
+            in_specs=[
+                pl.BlockSpec((1, KV, g, hd), _row_map),
+                pl.BlockSpec((1, bk, KV, hd), index_map),
+                pl.BlockSpec((1, bk, KV, hd), index_map),
+            ],
+            out_specs=pl.BlockSpec((1, KV, g, hd), _row_map),
+            scratch_shapes=_decode_scratch(KV, g, hd),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
+        interpret=interpret,
+    )(*prefetch, q.reshape(B, KV, g, hd), k, v)
+    return out.reshape(B, H, hd)
+
+
+def _verify_call(kernel, offsets, bt, q, ck, cv, k, v, *, bk, nk,
+                 index_map, interpret):
+    """One pool-layout chunk-verify ``pallas_call`` over a (B, nk + 1)
+    grid: nk cache blocks via ``index_map``, then the chunk step."""
+    B, S, H, hd = q.shape
+    KV = ck.shape[2]
+    rows = S * (H // KV)
+    prefetch = (offsets,) if bt is None else (offsets, bt.astype(jnp.int32))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B, nk + 1),
+            in_specs=[
+                pl.BlockSpec((1, KV, rows, hd), _row_map),
+                pl.BlockSpec((1, bk, KV, hd), index_map),
+                pl.BlockSpec((1, bk, KV, hd), index_map),
+                pl.BlockSpec((1, S, KV, hd), _row_map),
+                pl.BlockSpec((1, S, KV, hd), _row_map),
+            ],
+            out_specs=pl.BlockSpec((1, KV, rows, hd), _row_map),
+            scratch_shapes=_decode_scratch(KV, rows, hd),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), q.dtype),
+        interpret=interpret,
+    )(*prefetch, _chunk_q(q, KV), ck, cv, k, v)
+    return _chunk_out(out, S)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -371,36 +470,13 @@ def paged_slot_decode_attention(q, k, v, bt, kv_len, *, interpret=False):
     kv_len: (B,) valid lengths.  The block size is pinned to the page —
     pages are only contiguous within themselves.  Returns (B, H, hd).
     """
-    B, H, hd = q.shape
-    n_pages, page, KV = k.shape[0], k.shape[1], k.shape[2]
+    n_pages, page = k.shape[0], k.shape[1]
     nblk = bt.shape[1]
-    g = H // KV
-    qg = q.reshape(B, KV, g, hd)
-    kv_len = _scalar_rows(kv_len, B)
-    pmap = _page_index_map(n_pages, nblk)
-
-    out = pl.pallas_call(
+    hd = q.shape[2]
+    return _decode_call(
         functools.partial(_paged_slot_kernel, bk=page, scale=hd ** -0.5),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, KV, nblk),
-            in_specs=[
-                pl.BlockSpec((1, 1, g, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, page, 1, hd), pmap),
-                pl.BlockSpec((1, page, 1, hd), pmap),
-            ],
-            out_specs=pl.BlockSpec((1, 1, g, hd),
-                                   lambda b, h, j, *_: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
-        interpret=interpret,
-    )(kv_len, bt.astype(jnp.int32), qg, k, v)
-    return out.reshape(B, H, hd)
+        _scalar_rows(kv_len, q.shape[0]), bt, q, k, v, bk=page, nblk=nblk,
+        index_map=_page_index_map(n_pages, nblk), interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -412,38 +488,15 @@ def paged_ring_decode_attention(q, k, v, bt, slot_positions, *, window,
     ``s`` of row ``b`` lives at ``arena[bt[b, s // page], s % page]``.
     slot_positions: (B,) query positions, -1 for done rows.
     """
-    B, H, hd = q.shape
-    n_pages, page, KV = k.shape[0], k.shape[1], k.shape[2]
+    n_pages, page = k.shape[0], k.shape[1]
     nblk = bt.shape[1]
-    ring = nblk * page
-    g = H // KV
-    qg = q.reshape(B, KV, g, hd)
-    slot_positions = _scalar_rows(slot_positions, B)
-    pmap = _page_index_map(n_pages, nblk)
-
-    out = pl.pallas_call(
-        functools.partial(_paged_ring_kernel, bk=page, ring=ring,
+    hd = q.shape[2]
+    return _decode_call(
+        functools.partial(_paged_ring_kernel, bk=page, ring=nblk * page,
                           window=window, scale=hd ** -0.5),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, KV, nblk),
-            in_specs=[
-                pl.BlockSpec((1, 1, g, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, page, 1, hd), pmap),
-                pl.BlockSpec((1, page, 1, hd), pmap),
-            ],
-            out_specs=pl.BlockSpec((1, 1, g, hd),
-                                   lambda b, h, j, *_: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
-        interpret=interpret,
-    )(slot_positions, bt.astype(jnp.int32), qg, k, v)
-    return out.reshape(B, H, hd)
+        _scalar_rows(slot_positions, q.shape[0]), bt, q, k, v, bk=page,
+        nblk=nblk, index_map=_page_index_map(n_pages, nblk),
+        interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -453,49 +506,28 @@ def paged_chunk_verify_attention(q, ck, cv, bt, k, v, offsets, *, ring,
     """``chunk_verify_attention`` over a page arena (cache read-only).
 
     ck, cv: (n_pages, page, KV, hd) arenas; bt: (B, nblk); the logical
-    cache length is ``nblk * page``.  Grid axis 2 runs the nblk cache
+    cache length is ``nblk * page``.  Grid axis 1 runs the nblk cache
     blocks then one chunk step — the cache index_map clamps the chunk
     step's overrun to the last logical block before resolving the page.
     """
-    B, S, H, hd = q.shape
     n_pages, page, KV = ck.shape[0], ck.shape[1], ck.shape[2]
     nblk = bt.shape[1]
-    g = H // KV
-    qg = q.reshape(B, S, KV, g, hd)
-    offsets = _scalar_rows(offsets, B)
+    hd = q.shape[3]
+    return _verify_call(
+        functools.partial(_paged_chunk_kernel, bk=page, nk=nblk,
+                          g=q.shape[2] // KV, cache_len=nblk * page,
+                          ring=ring, window=window, scale=hd ** -0.5),
+        _scalar_rows(offsets, q.shape[0]), bt, q, ck, cv, k, v, bk=page,
+        nk=nblk, index_map=_page_index_map(n_pages, nblk),
+        interpret=interpret)
 
-    def cmap(b, h, j, scal_ref, bt_ref):
-        del scal_ref
-        jj = jnp.minimum(j, nblk - 1)
-        return (jnp.minimum(bt_ref[b, jj], n_pages - 1), 0, h, 0)
 
-    out = pl.pallas_call(
-        functools.partial(_paged_chunk_kernel, bk=page, nk=nblk, s_chunk=S,
-                          cache_len=nblk * page, ring=ring, window=window,
-                          scale=hd ** -0.5),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, KV, nblk + 1),
-            in_specs=[
-                pl.BlockSpec((1, S, 1, g, hd),
-                             lambda b, h, j, *_: (b, 0, h, 0, 0)),
-                pl.BlockSpec((1, page, 1, hd), cmap),
-                pl.BlockSpec((1, page, 1, hd), cmap),
-                pl.BlockSpec((1, S, 1, hd), lambda b, h, j, *_: (b, 0, h, 0)),
-                pl.BlockSpec((1, S, 1, hd), lambda b, h, j, *_: (b, 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, S, 1, g, hd),
-                                   lambda b, h, j, *_: (b, 0, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((S, g, 1), jnp.float32),
-                pltpu.VMEM((S, g, 1), jnp.float32),
-                pltpu.VMEM((S, g, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, S, KV, g, hd), q.dtype),
-        interpret=interpret,
-    )(offsets, bt.astype(jnp.int32), qg, ck, cv, k, v)
-    return out.reshape(B, S, H, hd)
+def _block_map(nk):
+    """Dense cache-operand index_map: block j of row b (the chunk-verify
+    grid's final step overruns to j == nk and clamps to the last block)."""
+    def index_map(b, j, *_):
+        return (b, jnp.minimum(j, nk - 1), 0, 0)
+    return index_map
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -506,39 +538,14 @@ def slot_decode_attention(q, k, v, kv_len, *, bk=None, interpret=False):
     kv_len: (B,) per-row valid lengths (0 = idle/done row -> exact zeros).
     Returns (B, H, hd).
     """
-    B, H, hd = q.shape
-    S, KV = k.shape[1], k.shape[2]
-    g = H // KV
+    S, hd = k.shape[1], q.shape[2]
     if bk is None:
         bk = _pick_bk(S)
     assert S % bk == 0, (S, bk)
-    qg = q.reshape(B, KV, g, hd)
-    kv_len = _scalar_rows(kv_len, B)
-
-    out = pl.pallas_call(
+    return _decode_call(
         functools.partial(_slot_kernel, bk=bk, scale=hd ** -0.5),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, KV, S // bk),
-            in_specs=[
-                pl.BlockSpec((1, 1, g, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, j, *_: (b, j, h, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, j, *_: (b, j, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, g, hd),
-                                   lambda b, h, j, *_: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
-        interpret=interpret,
-    )(kv_len, qg, k, v)
-    return out.reshape(B, H, hd)
+        _scalar_rows(kv_len, q.shape[0]), None, q, k, v, bk=bk,
+        nblk=S // bk, index_map=_block_map(S // bk), interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -554,40 +561,16 @@ def ring_decode_attention(q, k, v, slot_positions, *, window, bk=None,
     the ring modulus is the cache length itself (>= window once padded).
     Returns (B, H, hd).
     """
-    B, H, hd = q.shape
-    ring, KV = k.shape[1], k.shape[2]
-    g = H // KV
+    ring, hd = k.shape[1], q.shape[2]
     if bk is None:
         bk = _pick_bk(ring)
     assert ring % bk == 0, (ring, bk)
-    qg = q.reshape(B, KV, g, hd)
-    slot_positions = _scalar_rows(slot_positions, B)
-
-    out = pl.pallas_call(
+    return _decode_call(
         functools.partial(_ring_kernel, bk=bk, ring=ring, window=window,
                           scale=hd ** -0.5),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, KV, ring // bk),
-            in_specs=[
-                pl.BlockSpec((1, 1, g, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, j, *_: (b, j, h, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, j, *_: (b, j, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, g, hd),
-                                   lambda b, h, j, *_: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
-        interpret=interpret,
-    )(slot_positions, qg, k, v)
-    return out.reshape(B, H, hd)
+        _scalar_rows(slot_positions, q.shape[0]), None, q, k, v, bk=bk,
+        nblk=ring // bk, index_map=_block_map(ring // bk),
+        interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -603,44 +586,15 @@ def chunk_verify_attention(q, ck, cv, k, v, offsets, *, ring, window=None,
     zeros).  ``window`` adds the sliding-window band.  Returns
     (B, S, H, hd); the cache operands are never written.
     """
-    B, S, H, hd = q.shape
     Sc, KV = ck.shape[1], ck.shape[2]
-    g = H // KV
+    hd = q.shape[3]
     if bk is None:
         bk = _pick_bk(Sc)
     assert Sc % bk == 0, (Sc, bk)
     nk = Sc // bk
-    qg = q.reshape(B, S, KV, g, hd)
-    offsets = _scalar_rows(offsets, B)
-
-    out = pl.pallas_call(
-        functools.partial(_chunk_kernel, bk=bk, nk=nk, s_chunk=S,
+    return _verify_call(
+        functools.partial(_chunk_kernel, bk=bk, nk=nk, g=q.shape[2] // KV,
                           cache_len=Sc, ring=ring, window=window,
                           scale=hd ** -0.5),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, KV, nk + 1),
-            in_specs=[
-                pl.BlockSpec((1, S, 1, g, hd),
-                             lambda b, h, j, *_: (b, 0, h, 0, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, j, *_: (b, jnp.minimum(j, nk - 1),
-                                                  h, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, j, *_: (b, jnp.minimum(j, nk - 1),
-                                                  h, 0)),
-                pl.BlockSpec((1, S, 1, hd), lambda b, h, j, *_: (b, 0, h, 0)),
-                pl.BlockSpec((1, S, 1, hd), lambda b, h, j, *_: (b, 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, S, 1, g, hd),
-                                   lambda b, h, j, *_: (b, 0, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((S, g, 1), jnp.float32),
-                pltpu.VMEM((S, g, 1), jnp.float32),
-                pltpu.VMEM((S, g, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, S, KV, g, hd), q.dtype),
-        interpret=interpret,
-    )(offsets, qg, ck, cv, k, v)
-    return out.reshape(B, S, H, hd)
+        _scalar_rows(offsets, q.shape[0]), None, q, ck, cv, k, v, bk=bk,
+        nk=nk, index_map=_block_map(nk), interpret=interpret)

@@ -1,13 +1,12 @@
 """Public jit'd entry points for the Pallas kernels.
 
-TPU is the TARGET; this container is CPU-only, so ``interpret=True`` (the
-Pallas CPU interpreter) validates kernel-body semantics and the jnp refs in
-``ref.py`` serve as oracles.  On a real TPU deployment these wrappers run
-compiled (interpret=False) — callers select via ``mode``:
+The kernels are written for a TPU.  Callers select how they run via
+``mode``:
 
-  mode="auto"      — compiled on TPU backends, interpret elsewhere
-  mode="interpret" — force the interpreter (tests)
-  mode="reference" — the jnp oracle (lowering/dry-run path)
+  mode="auto"      — compiled Pallas; raises :class:`NoTPUError` on any
+                     backend that is not a TPU (never falls back)
+  mode="interpret" — the Pallas interpreter, on any backend (CPU tests)
+  mode="reference" — the jnp oracles in ``ref.py``
 """
 from __future__ import annotations
 
@@ -29,11 +28,22 @@ from repro.kernels.rglru_scan import rglru_scan as _rglru
 from repro.kernels.tr_sandwich import tr_sandwich as _sandwich
 
 
+class NoTPUError(RuntimeError):
+    """``mode="auto"`` asked for compiled kernels off a TPU backend."""
+
+
 def _interp(mode: str) -> bool:
     if mode == "interpret":
         return True
     if mode == "auto":
-        return jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend != "tpu":
+            raise NoTPUError(
+                f"mode='auto' runs the compiled Pallas kernels, which need "
+                f"a TPU backend, but JAX's default backend is {backend!r}; "
+                f"use mode='interpret' (the Pallas interpreter) or "
+                f"'reference' (the jnp oracles) off a TPU")
+        return False
     raise ValueError(mode)
 
 
@@ -158,7 +168,8 @@ def paged_latent_gather(arena, bt, *, mode="auto"):
     every other paged cache group and the oracle suite covers it."""
     if mode == "reference":
         return ref.paged_latent_gather_ref(arena, bt)
-    _interp(mode)  # validate the mode string
+    if mode not in ("auto", "interpret"):
+        raise ValueError(mode)
     n_pages = arena.shape[0]
     g = arena[jnp.minimum(jnp.asarray(bt, jnp.int32), n_pages - 1)]
     return g.reshape((bt.shape[0], -1) + arena.shape[2:])

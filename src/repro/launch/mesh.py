@@ -7,15 +7,15 @@ only ``dryrun.py`` forces 512 host devices.
 from __future__ import annotations
 
 import jax
-
-from repro.utils.compat import make_mesh_compat  # noqa: F401  (re-export)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serve_mesh(shape):
@@ -23,7 +23,8 @@ def make_serve_mesh(shape):
     string or (data, model) tuple — the launch-layer face of
     ``distributed/serve_sharding.py``."""
     from repro.distributed.serve_sharding import parse_mesh_arg
-    return make_mesh_compat(parse_mesh_arg(shape), ("data", "model"))
+    return jax.make_mesh(parse_mesh_arg(shape), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_host_mesh():
@@ -33,4 +34,5 @@ def make_host_mesh():
     subprocess tests with forced host device counts.
     """
     n = len(jax.devices())
-    return make_mesh_compat((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -52,6 +52,7 @@ from repro.serve import (
 )
 from repro.serve.engine import POLICIES
 from repro.train.steps import make_decode_step, make_prefill_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 @functools.lru_cache(maxsize=None)
@@ -231,10 +232,10 @@ def main():
     ap.add_argument("--kernel", default="jnp",
                     choices=["jnp", "auto", "interpret", "reference"],
                     help="slot-decode attention backend: jnp (pure-jnp "
-                         "model path), auto (Pallas kernels — compiled on "
-                         "TPU, interpreter elsewhere), interpret (Pallas "
-                         "CPU interpreter), reference (kernels/ref.py "
-                         "oracles)")
+                         "model path), auto (compiled Pallas kernels; "
+                         "needs a TPU and fails elsewhere), interpret "
+                         "(Pallas interpreter, any backend), reference "
+                         "(kernels/ref.py oracles)")
     ap.add_argument("--pool", default="dense", choices=["dense", "paged"],
                     help="continuous: slot-pool layout — dense (one full "
                          "max_len row per slot) or paged (block tables "
@@ -275,6 +276,7 @@ def main():
                          "'seed:S[:N]' for a seeded random plan "
                          "(chaos testing; see serve/faults.py)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).replace(decode_kernel=args.kernel)
     if args.engine == "continuous":
@@ -416,7 +418,9 @@ def main():
             mesh_arg = serve_sharding.choose_serve_mesh_shape(
                 len(jax.devices()), cfg, args.capacity)
         except ValueError as e:
-            print(f"[serve] {e} — serving single-device")
+            # serving on one of several visible devices would leave the
+            # rest idle without saying so: name the layout problem instead
+            raise SystemExit(f"error: {e}")
     engine = ContinuousBatchingEngine(cfg, params, capacity=args.capacity,
                                       max_len=max_len, k=args.k,
                                       policy=args.policy, pool=args.pool,

@@ -34,15 +34,7 @@ from repro.models import get_family
 from repro.optim import OptimizerConfig, make_optimizer, \
     linear_warmup_cosine
 from repro.train.steps import make_train_step
-
-# XLA flags a real TPU launch would set for compute/comm overlap (the
-# latency-hiding scheduler); harmless no-ops on CPU.
-TPU_PERF_FLAGS = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true "
-    "--xla_tpu_megacore_fusion_allow_ags=true "
-    "--xla_enable_async_all_gather=true "
-    "--xla_enable_async_collective_permute=true "
-)
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def data_for(cfg, batch, seq, seed=0, start_step=0):
@@ -169,6 +161,7 @@ def main():
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     _, hist = train(
         args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
         lr=args.lr, ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

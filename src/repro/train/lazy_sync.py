@@ -24,19 +24,13 @@ lines of shard_map.
 """
 from __future__ import annotations
 
-import contextlib
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.distributed.sharding import suspend_rules
 from repro.models import get_family
 from repro.optim import OptimizerConfig, make_optimizer
 from repro.train.loss import loss_for
-from repro.utils.compat import HAS_ABSTRACT_MESH, shard_map_compat
 
 
 def _data_axes(mesh):
@@ -76,13 +70,6 @@ def make_lazy_sync_train_step(cfg, opt_cfg: OptimizerConfig, mesh,
     ``param_shardings`` — the pytree of NamedShardings the params live in
     (FSDP layout).  Optimizer state must share the same layout.
     """
-    # Old jax cannot partition ``lax.scan`` while-loops inside partial-auto
-    # shard_map regions (manual-subgroup check in the SPMD partitioner);
-    # fully unrolling the layer/microbatch scans sidesteps the While HLO at
-    # the cost of O(L) program size — acceptable for the device counts old
-    # jax is realistically run at.
-    if not HAS_ABSTRACT_MESH:
-        cfg = cfg.replace(unroll_scans=True)
     fam = get_family(cfg)
     loss_fn = loss_for(cfg)
     _, update_fn = make_optimizer(opt_cfg, schedule)
@@ -100,41 +87,15 @@ def make_lazy_sync_train_step(cfg, opt_cfg: OptimizerConfig, mesh,
     gather_ax = jax.tree.map(lambda s: _gather_axis(s, manual), p_specs,
                              is_leaf=lambda x: isinstance(x, P))
 
-    # Old jax has no abstract-mesh introspection, so ``annotate`` cannot see
-    # it is inside a partial-manual region — and a constraint built on the
-    # concrete mesh there trips the SPMD partitioner's manual-subgroup
-    # check.  Suspend annotations for the body and let GSPMD infer layouts
-    # from the sharded operands.  New jax handles this inside ``annotate``.
-    if HAS_ABSTRACT_MESH:
-        def body_rules():
-            return contextlib.nullcontext()
-    else:
-        def body_rules():
-            return suspend_rules()
-
-    def body_inner(params_local, opt_local, batch_local, step, axis_idx):
+    def body(params_local, opt_local, batch_local, step):
         # (1) one all-gather (only over the axes each leaf is sharded on —
-        # leaves replicated over pod/data gather nothing).  Old jax's SPMD
-        # partitioner crashes on all_gather/psum_scatter of operands that
-        # are *also* auto-sharded over the model axis, so there the gather
-        # is emulated as pad-to-full + psum and the scatter as psum + slice
-        # (same semantics, full-size wire payload — still one collective
-        # round per step instead of per microbatch).
+        # leaves replicated over pod/data gather nothing)
         def gather(p, ax):
             if ax is None:
                 return p
             dim, axes = ax
             for a in reversed(axes):
-                if HAS_ABSTRACT_MESH:
-                    p = jax.lax.all_gather(p, a, axis=dim, tiled=True)
-                else:
-                    shard = p.shape[dim]
-                    full = jnp.zeros(
-                        p.shape[:dim] + (shard * sizes[a],)
-                        + p.shape[dim + 1:], p.dtype)
-                    full = jax.lax.dynamic_update_slice_in_dim(
-                        full, p, axis_idx[a][0] * shard, axis=dim)
-                    p = jax.lax.psum(full, a)
+                p = jax.lax.all_gather(p, a, axis=dim, tiled=True)
             return p
 
         params_full = jax.tree.map(gather, params_local, gather_ax)
@@ -165,11 +126,9 @@ def make_lazy_sync_train_step(cfg, opt_cfg: OptimizerConfig, mesh,
         m0 = jax.eval_shape(lambda: grad_fn(
             params_full, jax.tree.map(lambda x: x[0], micro))[0][1])
         m0 = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32), m0)
-        # cfg.unroll_scans is forced True on old jax above, which also
-        # fully unrolls this scan (While HLOs don't partition there)
         (grads_full, metrics), _ = jax.lax.scan(
             acc, (g0, m0), micro,
-            unroll=getattr(cfg, "unroll_scans", False))
+            unroll=cfg.unroll_scans)
 
         # (3) one reduce-scatter back to the FSDP shard layout; axes the
         # leaf is replicated over (e.g. pod) contribute a plain psum
@@ -178,14 +137,8 @@ def make_lazy_sync_train_step(cfg, opt_cfg: OptimizerConfig, mesh,
             if ax is not None:
                 dim, axes = ax
                 for a in axes:
-                    if HAS_ABSTRACT_MESH:
-                        g = jax.lax.psum_scatter(
-                            g, a, scatter_dimension=dim, tiled=True)
-                    else:
-                        g = jax.lax.psum(g, a)
-                        shard = g.shape[dim] // sizes[a]
-                        g = jax.lax.dynamic_slice_in_dim(
-                            g, axis_idx[a][0] * shard, shard, axis=dim)
+                    g = jax.lax.psum_scatter(
+                        g, a, scatter_dimension=dim, tiled=True)
                 done = axes
             for a in daxes:
                 if a not in done:
@@ -206,36 +159,12 @@ def make_lazy_sync_train_step(cfg, opt_cfg: OptimizerConfig, mesh,
         metrics.update(opt_metrics)
         return params_local, opt_local, metrics
 
-    def body(params_local, opt_local, batch_local, step, axis_idx=None):
-        with body_rules():
-            return body_inner(params_local, opt_local, batch_local, step,
-                              axis_idx)
-
     batch_spec = P(daxes if len(daxes) > 1 else daxes[0])
     opt_manual = {"m": p_manual, "v": p_manual}
     if opt_cfg.master_weights:
         opt_manual["master"] = p_manual
 
-    base_specs = (p_manual, opt_manual, batch_spec, P())
-    if HAS_ABSTRACT_MESH:
-        inner = shard_map_compat(
-            body, mesh, manual_axes=manual,
-            in_specs=base_specs, out_specs=(p_manual, opt_manual, P()))
-        return lambda params, opt_state, batch, step: inner(
-            params, opt_state, batch, step)
-
-    # Old jax only: per-axis device indices for the emulated collectives,
-    # passed as axis-sharded inputs so each shard reads its own coordinate
-    # from its (1,) slice.  (``jax.lax.axis_index`` lowers to PartitionId,
-    # which old jax's partitioner rejects inside partial-auto regions.)
-    idx_spec = {a: P(a) for a in daxes}
-    inner = shard_map_compat(
-        body, mesh, manual_axes=manual,
-        in_specs=base_specs + (idx_spec,),
-        out_specs=(p_manual, opt_manual, P()))
-
-    def step_fn(params, opt_state, batch, step):
-        axis_idx = {a: jnp.arange(sizes[a], dtype=jnp.int32) for a in daxes}
-        return inner(params, opt_state, batch, step, axis_idx)
-
-    return step_fn
+    return jax.shard_map(
+        body, mesh=mesh, axis_names=manual,
+        in_specs=(p_manual, opt_manual, batch_spec, P()),
+        out_specs=(p_manual, opt_manual, P()), check_vma=False)

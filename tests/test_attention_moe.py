@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.attention import attention, reference_attention
 from repro.models.moe import dispatch_combine, moe_mlp, router

@@ -30,8 +30,9 @@ def test_lazy_sync_matches_baseline():
         init_fn, _ = make_optimizer(opt_cfg)
         batch = {"tokens": jnp.asarray(lm_batch(cfg.vocab_size, 16, 32))}
 
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         rules = sharding_rules_for_mesh(mesh, fsdp=True)
         p_sh = params_shardings(fam.param_specs(cfg), mesh, rules,
                                 shapes=params)

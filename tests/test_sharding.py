@@ -107,8 +107,9 @@ def test_sharded_train_step_matches_single_device():
         # single device result
         p1, o1, m1 = jax.jit(step)(params, opt, batch, jnp.int32(1))
 
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         rules = sharding_rules_for_mesh(mesh)
         p_sh = params_shardings(fam.param_specs(cfg), mesh, rules,
                                 shapes=params)
@@ -163,8 +164,9 @@ def test_gradient_compression():
         from repro.distributed.compression import (make_crosspod_psum,
             init_error_feedback)
 
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         grads = {"w": jnp.asarray(np.random.default_rng(0)
                                   .standard_normal((8, 16)), jnp.float32)}
         # replicated grads: psum/n == identity -> lossless check of plumbing
