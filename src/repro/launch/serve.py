@@ -29,10 +29,16 @@ production scale.  Usable as a library (examples) or CLI:
   # draft proposals then go through rejection sampling)
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b-smoke \
       --engine continuous --temperature 0.8 --top-k 40 --top-p 0.95
+
+  # profile the serve run: the engine's serve.* host spans and its
+  # programs' device ops, by scope (docs/serving.md, "Tracing")
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b-smoke \
+      --engine continuous --profile /tmp/serve-profile
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import time
 
@@ -275,8 +281,13 @@ def main():
                          "nan/oom/slow/hang/malformed/crash, or "
                          "'seed:S[:N]' for a seeded random plan "
                          "(chaos testing; see serve/faults.py)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="capture a jax.profiler trace of the serve run "
+                         "(and of a live upgrade) into DIR")
     args = ap.parse_args()
     enable_compile_cache()
+    profile = (jax.profiler.trace(args.profile) if args.profile
+               else contextlib.nullcontext())
 
     cfg = get_config(args.arch).replace(decode_kernel=args.kernel)
     if args.engine == "continuous":
@@ -363,9 +374,10 @@ def main():
         prompts = jnp.asarray(lm_batch(cfg.vocab_size, args.batch,
                                        args.prompt_len))
         t0 = time.time()
-        toks = generate(cfg, params, prompts, max_new_tokens=args.gen,
-                        eos_id=args.eos_id)
-        toks.block_until_ready()
+        with profile:
+            toks = generate(cfg, params, prompts, max_new_tokens=args.gen,
+                            eos_id=args.eos_id)
+            toks.block_until_ready()
         dt = time.time() - t0
         toks_np = np.asarray(toks)
         if args.eos_id is None:
@@ -454,78 +466,80 @@ def main():
     if args.snapshot:
         path = snapshot_engine(engine, args.snapshot)
         print(f"[serve] engine snapshot -> {path}")
-    upgrade_mgr = None
-    if args.grow_cfg:
-        from repro.serve.upgrade import UpgradeError, UpgradeManager
+    with profile:
+        upgrade_mgr = None
+        if args.grow_cfg:
+            from repro.serve.upgrade import UpgradeError, UpgradeManager
+            try:
+                upgrade_mgr = UpgradeManager(
+                    engine, get_config(args.grow_cfg),
+                    method=args.grow_method, rank=args.grow_rank,
+                    grow_steps=args.grow_steps, spec_d=args.spec_d,
+                    upgrade_at=args.upgrade_at, probe_fp=True)
+            except UpgradeError as e:
+                raise SystemExit(f"error: --grow-cfg: {e}")
+            upgrade_mgr.start(background=not args.upgrade_sync)
+            mode = ("pre-grown" if args.upgrade_sync
+                    else "growing in background")
+            print(f"[serve] live upgrade armed: {cfg.name} -> "
+                  f"{upgrade_mgr.cfg_tgt.name} ({mode}, swap at dispatch "
+                  f">= {args.upgrade_at})")
+        rng = np.random.default_rng(0)
+        reqs = list(resumed)
+        known = {r.uid for r in resumed} | set(recovered)
+        for uid in range(args.batch):
+            if uid in known:
+                continue  # --resume already owns this uid
+            plen = int(rng.integers(max(1, args.prompt_len // 2),
+                                    args.prompt_len + 1))
+            prompt = lm_batch(cfg.vocab_size, 1, plen, seed=uid)[0]
+            reqs.append(Request(uid=uid, prompt=prompt,
+                                max_new_tokens=args.gen, eos_id=args.eos_id))
+        t0 = time.time()
         try:
-            upgrade_mgr = UpgradeManager(
-                engine, get_config(args.grow_cfg),
-                method=args.grow_method, rank=args.grow_rank,
-                grow_steps=args.grow_steps, spec_d=args.spec_d,
-                upgrade_at=args.upgrade_at, probe_fp=True)
-        except UpgradeError as e:
-            raise SystemExit(f"error: --grow-cfg: {e}")
-        upgrade_mgr.start(background=not args.upgrade_sync)
-        mode = "pre-grown" if args.upgrade_sync else "growing in background"
-        print(f"[serve] live upgrade armed: {cfg.name} -> "
-              f"{upgrade_mgr.cfg_tgt.name} ({mode}, swap at dispatch "
-              f">= {args.upgrade_at})")
-    rng = np.random.default_rng(0)
-    reqs = list(resumed)
-    known = {r.uid for r in resumed} | set(recovered)
-    for uid in range(args.batch):
-        if uid in known:
-            continue  # --resume already owns this uid
-        plen = int(rng.integers(max(1, args.prompt_len // 2),
-                                args.prompt_len + 1))
-        prompt = lm_batch(cfg.vocab_size, 1, plen, seed=uid)[0]
-        reqs.append(Request(uid=uid, prompt=prompt,
-                            max_new_tokens=args.gen, eos_id=args.eos_id))
-    t0 = time.time()
-    try:
-        out = engine.run(reqs)
-    except EngineKilled as e:
-        # the injected crash: the journal survived, the process "died" —
-        # exit cleanly so the kill/restart smoke can resume us
-        if journal is not None:
-            journal.close()
-        print(f"[serve] ENGINE KILLED ({e}) — journal at {args.journal} "
-              "holds the committed state; rerun with --resume")
-        return
-    dt = time.time() - t0
-    if upgrade_mgr is not None:
-        if upgrade_mgr.state in ("growing", "ready"):
-            # the trace finished before the background growth was ready:
-            # land the swap now so the NEXT trace serves the target
-            upgrade_mgr.wait()
-            upgrade_mgr.poll(engine)
-            print("[serve] upgrade: growth outlived the trace — swap "
-                  "landed at trace end")
-        if upgrade_mgr.state == "swapped":
-            spec_note = (f"draft={upgrade_mgr.cfg_src.name} "
-                         f"d={upgrade_mgr.spec_d}"
-                         if engine.speculative is not None else
-                         f"off ({upgrade_mgr.spec_reason})")
-            fp = upgrade_mgr.fp_token_agreement
-            page_note = ""
-            if upgrade_mgr.pages_resident_at_swap:
-                page_note = (
-                    f", pages {upgrade_mgr.pages_carried} carried / "
-                    f"{upgrade_mgr.pages_reprefilled} re-prefilled "
-                    f"({upgrade_mgr.pages_resident_at_swap} resident at "
-                    "swap)")
-            print(f"[serve] upgrade SWAPPED: {upgrade_mgr.cfg_src.name} "
-                  f"-> {upgrade_mgr.cfg_tgt.name} in "
-                  f"{upgrade_mgr.grow_seconds:.1f}s growth, pause "
-                  f"{upgrade_mgr.pause_ms:.0f} ms, "
-                  f"{upgrade_mgr.resumed} mid-flight resumed, "
-                  f"{engine.n_held_for_upgrade} held submits, "
-                  f"{len(engine.rejected)} dropped{page_note}; greedy "
-                  f"agreement {'n/a' if fp is None else f'{fp:.3f}'}; "
-                  f"post-swap speculation {spec_note}")
-        elif upgrade_mgr.state == "failed":
-            print(f"[serve] upgrade FAILED (engine kept serving "
-                  f"{cfg.name}): {upgrade_mgr.error}")
+            out = engine.run(reqs)
+        except EngineKilled as e:
+            # the injected crash: the journal survived, the process "died" —
+            # exit cleanly so the kill/restart smoke can resume us
+            if journal is not None:
+                journal.close()
+            print(f"[serve] ENGINE KILLED ({e}) — journal at {args.journal} "
+                  "holds the committed state; rerun with --resume")
+            return
+        dt = time.time() - t0
+        if upgrade_mgr is not None:
+            if upgrade_mgr.state in ("growing", "ready"):
+                # the trace finished before the background growth was ready:
+                # land the swap now so the NEXT trace serves the target
+                upgrade_mgr.wait()
+                upgrade_mgr.poll(engine)
+                print("[serve] upgrade: growth outlived the trace — swap "
+                      "landed at trace end")
+            if upgrade_mgr.state == "swapped":
+                spec_note = (f"draft={upgrade_mgr.cfg_src.name} "
+                             f"d={upgrade_mgr.spec_d}"
+                             if engine.speculative is not None else
+                             f"off ({upgrade_mgr.spec_reason})")
+                fp = upgrade_mgr.fp_token_agreement
+                page_note = ""
+                if upgrade_mgr.pages_resident_at_swap:
+                    page_note = (
+                        f", pages {upgrade_mgr.pages_carried} carried / "
+                        f"{upgrade_mgr.pages_reprefilled} re-prefilled "
+                        f"({upgrade_mgr.pages_resident_at_swap} resident at "
+                        "swap)")
+                print(f"[serve] upgrade SWAPPED: {upgrade_mgr.cfg_src.name} "
+                      f"-> {upgrade_mgr.cfg_tgt.name} in "
+                      f"{upgrade_mgr.grow_seconds:.1f}s growth, pause "
+                      f"{upgrade_mgr.pause_ms:.0f} ms, "
+                      f"{upgrade_mgr.resumed} mid-flight resumed, "
+                      f"{engine.n_held_for_upgrade} held submits, "
+                      f"{len(engine.rejected)} dropped{page_note}; greedy "
+                      f"agreement {'n/a' if fp is None else f'{fp:.3f}'}; "
+                      f"post-swap speculation {spec_note}")
+            elif upgrade_mgr.state == "failed":
+                print(f"[serve] upgrade FAILED (engine kept serving "
+                      f"{cfg.name}): {upgrade_mgr.error}")
     out = {**recovered, **out}
     n_tok = sum(len(v) for v in out.values())
     mode = "speculative" if speculative is not None else "continuous"

@@ -20,6 +20,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.serve import tracing
+
 NEG_INF = -1e30
 
 
@@ -274,25 +276,30 @@ def ring_slot_update_attend(q, cache, k, v, slot_positions, *, window,
     from repro.models.common import freeze_rows
 
     ring = cache["k"].shape[1]
-    b_idx = jnp.arange(k.shape[0])
-    slot_idx = slot_positions % ring
-    ck = cache["k"].at[b_idx, slot_idx].set(k[:, 0].astype(cache["k"].dtype))
-    cv = cache["v"].at[b_idx, slot_idx].set(v[:, 0].astype(cache["v"].dtype))
-    new_cache = {"k": ck, "v": cv}
-    if done is not None:
-        # done rows' frozen (token, position) re-store identical bytes
-        # anyway; the explicit freeze makes the no-op unconditional
-        new_cache = freeze_rows(cache, new_cache, done)
-    if kernel is not None:
-        assert scale is None, "the ring kernel fixes scale at hd**-0.5"
-        from repro.kernels import ops
-        out = ops.ring_decode_attention(
-            q[:, 0], new_cache["k"], new_cache["v"], slot_positions,
-            window=window, done=done, mode=kernel)[:, None]
-        return out, new_cache
-    out = ring_slot_attend(q, new_cache["k"].astype(q.dtype),
-                           new_cache["v"].astype(q.dtype), slot_positions,
-                           window=window, scale=scale, done=done)
+    with tracing.scope("attn.kv_write"):
+        b_idx = jnp.arange(k.shape[0])
+        slot_idx = slot_positions % ring
+        ck = cache["k"].at[b_idx, slot_idx].set(
+            k[:, 0].astype(cache["k"].dtype))
+        cv = cache["v"].at[b_idx, slot_idx].set(
+            v[:, 0].astype(cache["v"].dtype))
+        new_cache = {"k": ck, "v": cv}
+        if done is not None:
+            # done rows' frozen (token, position) re-store identical bytes
+            # anyway; the explicit freeze makes the no-op unconditional
+            new_cache = freeze_rows(cache, new_cache, done)
+    with tracing.scope("attn.core"):
+        if kernel is not None:
+            assert scale is None, "the ring kernel fixes scale at hd**-0.5"
+            from repro.kernels import ops
+            out = ops.ring_decode_attention(
+                q[:, 0], new_cache["k"], new_cache["v"], slot_positions,
+                window=window, done=done, mode=kernel)[:, None]
+        else:
+            out = ring_slot_attend(
+                q, new_cache["k"].astype(q.dtype),
+                new_cache["v"].astype(q.dtype), slot_positions,
+                window=window, scale=scale, done=done)
     return out, new_cache
 
 
@@ -330,27 +337,29 @@ def paged_ring_slot_update_attend(q, cache, k, v, slot_positions, *,
     bt = cache["bt"]
     n_pages, page = cache["k"].shape[:2]
     ring = bt.shape[1] * page
-    sidx = slot_positions % ring
-    pid = jnp.take_along_axis(bt, (sidx // page)[:, None], axis=1)[:, 0]
-    if done is not None:
-        pid = jnp.where(done, n_pages, pid)
-    off = sidx % page
-    ck = cache["k"].at[pid, off].set(k[:, 0].astype(cache["k"].dtype),
-                                     mode="drop")
-    cv = cache["v"].at[pid, off].set(v[:, 0].astype(cache["v"].dtype),
-                                     mode="drop")
+    with tracing.scope("attn.kv_write"):
+        sidx = slot_positions % ring
+        pid = jnp.take_along_axis(bt, (sidx // page)[:, None], axis=1)[:, 0]
+        if done is not None:
+            pid = jnp.where(done, n_pages, pid)
+        off = sidx % page
+        ck = cache["k"].at[pid, off].set(k[:, 0].astype(cache["k"].dtype),
+                                         mode="drop")
+        cv = cache["v"].at[pid, off].set(v[:, 0].astype(cache["v"].dtype),
+                                         mode="drop")
     new_cache = {"k": ck, "v": cv, "bt": bt}
-    if kernel is not None:
-        assert scale is None, "the ring kernel fixes scale at hd**-0.5"
-        from repro.kernels import ops
-        out = ops.paged_ring_decode_attention(
-            q[:, 0], ck, cv, bt, slot_positions, window=window, done=done,
-            mode=kernel)[:, None]
-        return out, new_cache
-    out = ring_slot_attend(q, paged_gather(ck, bt).astype(q.dtype),
-                           paged_gather(cv, bt).astype(q.dtype),
-                           slot_positions, window=window, scale=scale,
-                           done=done)
+    with tracing.scope("attn.core"):
+        if kernel is not None:
+            assert scale is None, "the ring kernel fixes scale at hd**-0.5"
+            from repro.kernels import ops
+            out = ops.paged_ring_decode_attention(
+                q[:, 0], ck, cv, bt, slot_positions, window=window,
+                done=done, mode=kernel)[:, None]
+        else:
+            out = ring_slot_attend(q, paged_gather(ck, bt).astype(q.dtype),
+                                   paged_gather(cv, bt).astype(q.dtype),
+                                   slot_positions, window=window,
+                                   scale=scale, done=done)
     return out, new_cache
 
 

@@ -34,6 +34,7 @@ from repro.models.common import (
     trunc_normal,
 )
 from repro.models.rope import apply_mrope, apply_rope
+from repro.serve import tracing
 
 
 # =============================================================== param init
@@ -216,28 +217,31 @@ def _paged_slot_forward(q, p, cfg, cache, k, v, slot_positions, slot_done,
             q, cache, k, v, slot_positions, window=window, done=slot_done,
             kernel=_kernel_mode(cfg))
         return _attn_out(out, p, cfg, cdt), new_cache
-    blk = slot_positions // page
-    pid = jnp.take_along_axis(bt, blk[:, None], axis=1)[:, 0]
-    if slot_done is not None:
-        pid = jnp.where(slot_done, n_pages, pid)
-    off = slot_positions % page
-    ck = cache["k"].at[pid, off].set(k[:, 0].astype(cache["k"].dtype),
-                                     mode="drop")
-    cv = cache["v"].at[pid, off].set(v[:, 0].astype(cache["v"].dtype),
-                                     mode="drop")
+    with tracing.scope("attn.kv_write"):
+        blk = slot_positions // page
+        pid = jnp.take_along_axis(bt, blk[:, None], axis=1)[:, 0]
+        if slot_done is not None:
+            pid = jnp.where(slot_done, n_pages, pid)
+        off = slot_positions % page
+        ck = cache["k"].at[pid, off].set(k[:, 0].astype(cache["k"].dtype),
+                                         mode="drop")
+        cv = cache["v"].at[pid, off].set(v[:, 0].astype(cache["v"].dtype),
+                                         mode="drop")
     new_cache = {"k": ck, "v": cv, "bt": bt}
-    kvl = _slot_kv_len(slot_positions, slot_done)
     kmode = _kernel_mode(cfg)
-    if kmode is not None:
-        from repro.kernels import ops
-        out = ops.paged_slot_decode_attention(
-            q[:, 0], ck, cv, bt, kvl, mode=kmode)[:, None]
-    else:
-        out = attn_lib.attention(
-            q, attn_lib.paged_gather(ck, bt).astype(cdt),
-            attn_lib.paged_gather(cv, bt).astype(cdt), causal=False,
-            kv_len=kvl, chunk_q=cfg.attn_chunk, unroll=cfg.unroll_scans,
-            logits_dtype=jnp.dtype(cfg.attn_logits_dtype))
+    with tracing.scope("attn.core"):
+        kvl = _slot_kv_len(slot_positions, slot_done)
+        if kmode is not None:
+            from repro.kernels import ops
+            out = ops.paged_slot_decode_attention(
+                q[:, 0], ck, cv, bt, kvl, mode=kmode)[:, None]
+        else:
+            out = attn_lib.attention(
+                q, attn_lib.paged_gather(ck, bt).astype(cdt),
+                attn_lib.paged_gather(cv, bt).astype(cdt), causal=False,
+                kv_len=kvl, chunk_q=cfg.attn_chunk,
+                unroll=cfg.unroll_scans,
+                logits_dtype=jnp.dtype(cfg.attn_logits_dtype))
     return _attn_out(out, p, cfg, cdt), new_cache
 
 
@@ -277,32 +281,33 @@ def _attn_forward(x, p, cfg, positions, *, cache=None, q_offset=0,
                             slot_done=slot_done,
                             chunk_offsets=chunk_offsets)
 
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"].astype(cdt))
-    k = jnp.einsum("bsd,dh->bsh", x, p["wk"].astype(cdt))
-    v = jnp.einsum("bsd,dh->bsh", x, p["wv"].astype(cdt))
-    if cfg.qkv_bias:
-        q = q + p["bq"].astype(cdt)
-        k = k + p["bk"].astype(cdt)
-        v = v + p["bv"].astype(cdt)
-    q = _split_heads(q, H, hd)
-    k = _split_heads(k, KV, hd)
-    v = _split_heads(v, KV, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
-    if cfg.rope == "standard":
-        q = apply_rope(q, positions, theta=cfg.rope_theta,
-                       fraction=cfg.rope_fraction)
-        k = apply_rope(k, positions, theta=cfg.rope_theta,
-                       fraction=cfg.rope_fraction)
-    elif cfg.rope == "mrope":
-        q = apply_mrope(q, positions, theta=cfg.rope_theta,
-                        sections=cfg.mrope_sections)
-        k = apply_mrope(k, positions, theta=cfg.rope_theta,
-                        sections=cfg.mrope_sections)
-    q = annotate(q, ("batch", "seq", "heads", "head_dim"))
-    k = annotate(k, ("batch", "seq", "kv_heads", "head_dim"))
-    v = annotate(v, ("batch", "seq", "kv_heads", "head_dim"))
+    with tracing.scope("attn.qkv"):
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"].astype(cdt))
+        k = jnp.einsum("bsd,dh->bsh", x, p["wk"].astype(cdt))
+        v = jnp.einsum("bsd,dh->bsh", x, p["wv"].astype(cdt))
+        if cfg.qkv_bias:
+            q = q + p["bq"].astype(cdt)
+            k = k + p["bk"].astype(cdt)
+            v = v + p["bv"].astype(cdt)
+        q = _split_heads(q, H, hd)
+        k = _split_heads(k, KV, hd)
+        v = _split_heads(v, KV, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"])
+            k = rms_norm(k, p["k_norm"])
+        if cfg.rope == "standard":
+            q = apply_rope(q, positions, theta=cfg.rope_theta,
+                           fraction=cfg.rope_fraction)
+            k = apply_rope(k, positions, theta=cfg.rope_theta,
+                           fraction=cfg.rope_fraction)
+        elif cfg.rope == "mrope":
+            q = apply_mrope(q, positions, theta=cfg.rope_theta,
+                            sections=cfg.mrope_sections)
+            k = apply_mrope(k, positions, theta=cfg.rope_theta,
+                            sections=cfg.mrope_sections)
+        q = annotate(q, ("batch", "seq", "heads", "head_dim"))
+        k = annotate(k, ("batch", "seq", "kv_heads", "head_dim"))
+        v = annotate(v, ("batch", "seq", "kv_heads", "head_dim"))
 
     if chunk_offsets is not None:
         # speculative verify: attend [cache ‖ chunk] read-only.  The
@@ -310,31 +315,31 @@ def _attn_forward(x, p, cfg, positions, *, cache=None, q_offset=0,
         # pages through the live cache's own "bt".
         is_ring = _is_ring(_cache_seq_len(cache), window)
         kmode = _kernel_mode(cfg)
-        if "bt" in cache:
-            if kmode is not None:
+        with tracing.scope("attn.core"):
+            if "bt" in cache and kmode is not None:
                 from repro.kernels import ops
                 out = ops.paged_chunk_verify_attention(
                     q, cache["k"], cache["v"], cache["bt"], k, v,
                     chunk_offsets, ring=is_ring, window=window,
                     done=slot_done, mode=kmode)
-            else:
+            elif "bt" in cache:
                 out = attn_lib.chunk_verify_attend(
                     q, attn_lib.paged_gather(cache["k"], cache["bt"]),
                     attn_lib.paged_gather(cache["v"], cache["bt"]),
                     k, v, chunk_offsets, ring=is_ring, window=window,
                     done=slot_done,
                     logits_dtype=jnp.dtype(cfg.attn_logits_dtype))
-            return _attn_out(out, p, cfg, cdt), {"k": k, "v": v}
-        if kmode is not None:
-            from repro.kernels import ops
-            out = ops.chunk_verify_attention(
-                q, cache["k"], cache["v"], k, v, chunk_offsets,
-                ring=is_ring, window=window, done=slot_done, mode=kmode)
-        else:
-            out = attn_lib.chunk_verify_attend(
-                q, cache["k"], cache["v"], k, v, chunk_offsets,
-                ring=is_ring, window=window, done=slot_done,
-                logits_dtype=jnp.dtype(cfg.attn_logits_dtype))
+            elif kmode is not None:
+                from repro.kernels import ops
+                out = ops.chunk_verify_attention(
+                    q, cache["k"], cache["v"], k, v, chunk_offsets,
+                    ring=is_ring, window=window, done=slot_done,
+                    mode=kmode)
+            else:
+                out = attn_lib.chunk_verify_attend(
+                    q, cache["k"], cache["v"], k, v, chunk_offsets,
+                    ring=is_ring, window=window, done=slot_done,
+                    logits_dtype=jnp.dtype(cfg.attn_logits_dtype))
         return _attn_out(out, p, cfg, cdt), {"k": k, "v": v}
 
     new_cache = None
@@ -365,24 +370,26 @@ def _attn_forward(x, p, cfg, positions, *, cache=None, q_offset=0,
         # token and position are frozen, so the write re-stores the exact
         # same K/V values (a bit-identical no-op) while kv_len == 0 keeps
         # the position unreadable.
-        b_idx = jnp.arange(B)
-        ck = cache["k"].at[b_idx, slot_positions].set(
-            k[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[b_idx, slot_positions].set(
-            v[:, 0].astype(cache["v"].dtype))
+        with tracing.scope("attn.kv_write"):
+            b_idx = jnp.arange(B)
+            ck = cache["k"].at[b_idx, slot_positions].set(
+                k[:, 0].astype(cache["k"].dtype))
+            cv = cache["v"].at[b_idx, slot_positions].set(
+                v[:, 0].astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
         kmode = _kernel_mode(cfg)
-        if kmode is not None:
-            from repro.kernels import ops
-            out = ops.slot_decode_attention(
-                q[:, 0], ck, cv, _slot_kv_len(slot_positions, slot_done),
-                mode=kmode)[:, None]
-        else:
-            out = attn_lib.attention(
-                q, ck.astype(cdt), cv.astype(cdt), causal=False,
-                kv_len=_slot_kv_len(slot_positions, slot_done),
-                chunk_q=cfg.attn_chunk, unroll=cfg.unroll_scans,
-                logits_dtype=jnp.dtype(cfg.attn_logits_dtype))
+        with tracing.scope("attn.core"):
+            kvl = _slot_kv_len(slot_positions, slot_done)
+            if kmode is not None:
+                from repro.kernels import ops
+                out = ops.slot_decode_attention(
+                    q[:, 0], ck, cv, kvl, mode=kmode)[:, None]
+            else:
+                out = attn_lib.attention(
+                    q, ck.astype(cdt), cv.astype(cdt), causal=False,
+                    kv_len=kvl, chunk_q=cfg.attn_chunk,
+                    unroll=cfg.unroll_scans,
+                    logits_dtype=jnp.dtype(cfg.attn_logits_dtype))
         return _attn_out(out, p, cfg, cdt), new_cache
     if cache is not None:
         # cache: {"k": (B, Smax, KV, hd), "v": ...} — window caches are ring
@@ -392,32 +399,37 @@ def _attn_forward(x, p, cfg, positions, *, cache=None, q_offset=0,
         if _is_ring(wsize, window):
             # the ring modulus is the CACHE length (>= window once the
             # pool pads to a kernel block multiple), not the window
-            if plens is not None and S > 1:
-                # admission prefill of tail-padded prompts: fill each
-                # row's ring from its TRUE length
-                ck = attn_lib.ring_fill_rows(k, plens, wsize, ck.dtype)
-                cv = attn_lib.ring_fill_rows(v, plens, wsize, cv.dtype)
-            else:
-                w_eff = min(S, wsize)
-                idx = (q_offset + S - w_eff + jnp.arange(w_eff)) % wsize
-                ck = ck.at[:, idx].set(k[:, -w_eff:].astype(ck.dtype))
-                cv = cv.at[:, idx].set(v[:, -w_eff:].astype(cv.dtype))
+            with tracing.scope("attn.kv_write"):
+                if plens is not None and S > 1:
+                    # admission prefill of tail-padded prompts: fill each
+                    # row's ring from its TRUE length
+                    ck = attn_lib.ring_fill_rows(k, plens, wsize, ck.dtype)
+                    cv = attn_lib.ring_fill_rows(v, plens, wsize, cv.dtype)
+                else:
+                    w_eff = min(S, wsize)
+                    idx = (q_offset + S - w_eff + jnp.arange(w_eff)) % wsize
+                    ck = ck.at[:, idx].set(k[:, -w_eff:].astype(ck.dtype))
+                    cv = cv.at[:, idx].set(v[:, -w_eff:].astype(cv.dtype))
             new_cache = {"k": ck, "v": cv}
-            if S > 1:
-                # prefill: window attention over the in-flight k/v directly
-                out = attn_lib.attention(
-                    q, k, v, causal=cfg.causal, window=window,
-                    q_offset=q_offset, chunk_q=cfg.attn_chunk,
-                    unroll=cfg.unroll_scans)
-            else:
-                kpos_abs = _ring_positions(q_offset + S, wsize)
-                out = _ring_window_attend(q, ck.astype(cdt), cv.astype(cdt),
-                                          kpos_abs, q_offset, cfg)
+            with tracing.scope("attn.core"):
+                if S > 1:
+                    # prefill: window attention over the in-flight k/v
+                    # directly
+                    out = attn_lib.attention(
+                        q, k, v, causal=cfg.causal, window=window,
+                        q_offset=q_offset, chunk_q=cfg.attn_chunk,
+                        unroll=cfg.unroll_scans)
+                else:
+                    kpos_abs = _ring_positions(q_offset + S, wsize)
+                    out = _ring_window_attend(
+                        q, ck.astype(cdt), cv.astype(cdt), kpos_abs,
+                        q_offset, cfg)
             return _attn_out(out, p, cfg, cdt), new_cache
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            ck, k.astype(ck.dtype), q_offset, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cv, v.astype(cv.dtype), q_offset, axis=1)
+        with tracing.scope("attn.kv_write"):
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                ck, k.astype(ck.dtype), q_offset, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cv, v.astype(cv.dtype), q_offset, axis=1)
         new_cache = {"k": ck, "v": cv}
         k, v = ck.astype(cdt), cv.astype(cdt)
         kv_len = q_offset + S
@@ -436,30 +448,33 @@ def _attn_forward(x, p, cfg, positions, *, cache=None, q_offset=0,
             blk = _flash_block(S)
             if blk is not None:
                 from repro.kernels import ops
-                of = ops.flash_attention(
-                    q.transpose(0, 2, 1, 3),
-                    k[:, :S].transpose(0, 2, 1, 3),
-                    v[:, :S].transpose(0, 2, 1, 3),
-                    causal=True, mode=kmode,
-                    **({} if kmode == "reference"
-                       else {"bq": blk, "bk": blk}))
+                with tracing.scope("attn.core"):
+                    of = ops.flash_attention(
+                        q.transpose(0, 2, 1, 3),
+                        k[:, :S].transpose(0, 2, 1, 3),
+                        v[:, :S].transpose(0, 2, 1, 3),
+                        causal=True, mode=kmode,
+                        **({} if kmode == "reference"
+                           else {"bq": blk, "bk": blk}))
                 return (_attn_out(of.transpose(0, 2, 1, 3), p, cfg, cdt),
                         new_cache)
 
-    out = attn_lib.attention(
-        q, k, v, causal=cfg.causal, window=window, q_offset=q_offset,
-        kv_len=kv_len, chunk_q=cfg.attn_chunk, unroll=cfg.unroll_scans,
-        logits_dtype=jnp.dtype(cfg.attn_logits_dtype),
-        prefix_chunks=cfg.attn_prefix_chunks)
+    with tracing.scope("attn.core"):
+        out = attn_lib.attention(
+            q, k, v, causal=cfg.causal, window=window, q_offset=q_offset,
+            kv_len=kv_len, chunk_q=cfg.attn_chunk, unroll=cfg.unroll_scans,
+            logits_dtype=jnp.dtype(cfg.attn_logits_dtype),
+            prefix_chunks=cfg.attn_prefix_chunks)
     return _attn_out(out, p, cfg, cdt), new_cache
 
 
 def _attn_out(out, p, cfg, cdt):
     B, S = out.shape[:2]
-    out = out.reshape(B, S, -1)
-    y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
-    if cfg.attn_out_bias:
-        y = y + p["bo"].astype(cdt)
+    with tracing.scope("attn.out"):
+        out = out.reshape(B, S, -1)
+        y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
+        if cfg.attn_out_bias:
+            y = y + p["bo"].astype(cdt)
     return y
 
 
@@ -495,17 +510,18 @@ def _mla_forward(x, p, cfg, positions, *, cache=None, q_offset=0, kv_len=None,
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
 
-    cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(cdt))
-    cq = rms_norm(cq, p["q_norm"])
-    q = jnp.einsum("bsr,rh->bsh", cq, p["w_uq"].astype(cdt))
-    q = q.reshape(B, S, H, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta)
+    with tracing.scope("attn.qkv"):
+        cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(cdt))
+        cq = rms_norm(cq, p["q_norm"])
+        q = jnp.einsum("bsr,rh->bsh", cq, p["w_uq"].astype(cdt))
+        q = q.reshape(B, S, H, dn + dr)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+        q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta)
 
-    ckv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(cdt))
-    kr = jnp.einsum("bsd,dr->bsr", x, p["w_kr"].astype(cdt))
-    kr = apply_rope(kr[:, :, None, :], positions,
-                    theta=cfg.rope_theta)[:, :, 0]
+        ckv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(cdt))
+        kr = jnp.einsum("bsd,dr->bsr", x, p["w_kr"].astype(cdt))
+        kr = apply_rope(kr[:, :, None, :], positions,
+                        theta=cfg.rope_theta)[:, :, 0]
 
     if chunk_offsets is not None:
         # speculative verify in the latent space: absorbed-weight
@@ -513,14 +529,14 @@ def _mla_forward(x, p, cfg, positions, *, cache=None, q_offset=0, kv_len=None,
         # offsets, cache read-only; the raw chunk latents are the pending
         # entry for ``commit_slots`` (never carrying a block table —
         # commit resolves pages through the live cache's own "bt")
-        cache_view = cache
-        if "bt" in cache:
-            cc, cr = _mla_paged_gather(cache, cfg)
-            cache_view = {"ckv": cc, "kr": cr}
-        out = _mla_chunk_verify(q_nope, q_rope, cache_view, ckv, kr, p, cfg,
-                                chunk_offsets, slot_done)
-        y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
-        return y, {"ckv": ckv, "kr": kr}
+        with tracing.scope("attn.core"):
+            cache_view = cache
+            if "bt" in cache:
+                cc, cr = _mla_paged_gather(cache, cfg)
+                cache_view = {"ckv": cc, "kr": cr}
+            out = _mla_chunk_verify(q_nope, q_rope, cache_view, ckv, kr, p,
+                                    cfg, chunk_offsets, slot_done)
+        return _mla_out(out, p, cdt), {"ckv": ckv, "kr": kr}
     new_cache = None
     if slot_positions is not None:
         if "bt" in cache:
@@ -530,77 +546,85 @@ def _mla_forward(x, p, cfg, positions, *, cache=None, q_offset=0, kv_len=None,
             # gathered dense view
             bt = cache["bt"]
             n_pages, page = cache["ckv"].shape[:2]
-            blk = slot_positions // page
-            pid = jnp.take_along_axis(bt, blk[:, None], axis=1)[:, 0]
-            if slot_done is not None:
-                pid = jnp.where(slot_done, n_pages, pid)
-            off = slot_positions % page
-            cc = cache["ckv"].at[pid, off].set(
-                ckv[:, 0].astype(cache["ckv"].dtype), mode="drop")
-            cr = cache["kr"].at[pid, off].set(
-                kr[:, 0].astype(cache["kr"].dtype), mode="drop")
+            with tracing.scope("attn.kv_write"):
+                blk = slot_positions // page
+                pid = jnp.take_along_axis(bt, blk[:, None], axis=1)[:, 0]
+                if slot_done is not None:
+                    pid = jnp.where(slot_done, n_pages, pid)
+                off = slot_positions % page
+                cc = cache["ckv"].at[pid, off].set(
+                    ckv[:, 0].astype(cache["ckv"].dtype), mode="drop")
+                cr = cache["kr"].at[pid, off].set(
+                    kr[:, 0].astype(cache["kr"].dtype), mode="drop")
             new_cache = {"ckv": cc, "kr": cr, "bt": bt}
-            gc, gr = _mla_paged_gather(new_cache, cfg)
-            out = _mla_absorbed_decode(
-                q_nope, q_rope, gc.astype(cdt), gr.astype(cdt), p, cfg,
-                kv_len=_slot_kv_len(slot_positions, slot_done))
-            y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
-            return y, new_cache
+            with tracing.scope("attn.core"):
+                gc, gr = _mla_paged_gather(new_cache, cfg)
+                out = _mla_absorbed_decode(
+                    q_nope, q_rope, gc.astype(cdt), gr.astype(cdt), p, cfg,
+                    kv_len=_slot_kv_len(slot_positions, slot_done))
+            return _mla_out(out, p, cdt), new_cache
         # continuous-batching decode: per-row latent-cache scatter + the
         # absorbed-weight attention with per-row valid lengths
-        b_idx = jnp.arange(B)
-        cc = cache["ckv"].at[b_idx, slot_positions].set(
-            ckv[:, 0].astype(cache["ckv"].dtype))
-        cr = cache["kr"].at[b_idx, slot_positions].set(
-            kr[:, 0].astype(cache["kr"].dtype))
+        with tracing.scope("attn.kv_write"):
+            b_idx = jnp.arange(B)
+            cc = cache["ckv"].at[b_idx, slot_positions].set(
+                ckv[:, 0].astype(cache["ckv"].dtype))
+            cr = cache["kr"].at[b_idx, slot_positions].set(
+                kr[:, 0].astype(cache["kr"].dtype))
         new_cache = {"ckv": cc, "kr": cr}
-        out = _mla_absorbed_decode(
-            q_nope, q_rope, cc.astype(cdt), cr.astype(cdt), p, cfg,
-            kv_len=_slot_kv_len(slot_positions, slot_done))
-        y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
-        return y, new_cache
+        with tracing.scope("attn.core"):
+            out = _mla_absorbed_decode(
+                q_nope, q_rope, cc.astype(cdt), cr.astype(cdt), p, cfg,
+                kv_len=_slot_kv_len(slot_positions, slot_done))
+        return _mla_out(out, p, cdt), new_cache
     if cache is not None:
         cc, cr = cache["ckv"], cache["kr"]
-        cc = jax.lax.dynamic_update_slice_in_dim(
-            cc, ckv.astype(cc.dtype), q_offset, axis=1)
-        cr = jax.lax.dynamic_update_slice_in_dim(
-            cr, kr.astype(cr.dtype), q_offset, axis=1)
+        with tracing.scope("attn.kv_write"):
+            cc = jax.lax.dynamic_update_slice_in_dim(
+                cc, ckv.astype(cc.dtype), q_offset, axis=1)
+            cr = jax.lax.dynamic_update_slice_in_dim(
+                cr, kr.astype(cr.dtype), q_offset, axis=1)
         new_cache = {"ckv": cc, "kr": cr}
         if S == 1:
             # Absorbed-weight MLA decode (DeepSeek-V3 §: W_uk folded into q,
             # W_uv applied after the latent attention) — attends directly in
             # the compressed kv_lora space, avoiding re-expanding K/V to
             # (B, S_cache, H, dn+dv) every step.
-            out = _mla_absorbed_decode(
-                q_nope, q_rope, cc.astype(cdt), cr.astype(cdt), p, cfg,
-                kv_len=q_offset + 1)
-            y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
-            return y, new_cache
+            with tracing.scope("attn.core"):
+                out = _mla_absorbed_decode(
+                    q_nope, q_rope, cc.astype(cdt), cr.astype(cdt), p, cfg,
+                    kv_len=q_offset + 1)
+            return _mla_out(out, p, cdt), new_cache
         ckv, kr = cc.astype(cdt), cr.astype(cdt)
         kv_len = q_offset + S
 
-    ckv_n = rms_norm(ckv, p["kv_norm"])
-    k_nope = jnp.einsum("bsr,rh->bsh", ckv_n, p["w_uk"].astype(cdt))
-    k_nope = k_nope.reshape(B, -1, H, dn)
-    v = jnp.einsum("bsr,rh->bsh", ckv_n, p["w_uv"].astype(cdt))
-    v = v.reshape(B, -1, H, dv)
+    with tracing.scope("attn.core"):
+        ckv_n = rms_norm(ckv, p["kv_norm"])
+        k_nope = jnp.einsum("bsr,rh->bsh", ckv_n, p["w_uk"].astype(cdt))
+        k_nope = k_nope.reshape(B, -1, H, dn)
+        v = jnp.einsum("bsr,rh->bsh", ckv_n, p["w_uv"].astype(cdt))
+        v = v.reshape(B, -1, H, dv)
 
-    qf = jnp.concatenate([q_nope, q_rope], -1)
-    kf = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(kr[:, :, None, :],
-                                  (*k_nope.shape[:3], dr))], -1)
-    qf = annotate(qf, ("batch", "seq", "heads", "head_dim"))
-    kf = annotate(kf, ("batch", "seq", "heads", "head_dim"))
-    v = annotate(v, ("batch", "seq", "heads", "head_dim"))
-    out = attn_lib.attention(
-        qf, kf, v, causal=cfg.causal, q_offset=q_offset, kv_len=kv_len,
-        scale=(dn + dr) ** -0.5, chunk_q=cfg.attn_chunk,
-        unroll=cfg.unroll_scans,
-        logits_dtype=jnp.dtype(cfg.attn_logits_dtype),
-        prefix_chunks=cfg.attn_prefix_chunks)
-    out = out.reshape(B, S, H * dv)
-    y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
-    return y, new_cache
+        qf = jnp.concatenate([q_nope, q_rope], -1)
+        kf = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(kr[:, :, None, :],
+                                      (*k_nope.shape[:3], dr))], -1)
+        qf = annotate(qf, ("batch", "seq", "heads", "head_dim"))
+        kf = annotate(kf, ("batch", "seq", "heads", "head_dim"))
+        v = annotate(v, ("batch", "seq", "heads", "head_dim"))
+        out = attn_lib.attention(
+            qf, kf, v, causal=cfg.causal, q_offset=q_offset, kv_len=kv_len,
+            scale=(dn + dr) ** -0.5, chunk_q=cfg.attn_chunk,
+            unroll=cfg.unroll_scans,
+            logits_dtype=jnp.dtype(cfg.attn_logits_dtype),
+            prefix_chunks=cfg.attn_prefix_chunks)
+        out = out.reshape(B, S, H * dv)
+    return _mla_out(out, p, cdt), new_cache
+
+
+def _mla_out(out, p, cdt):
+    with tracing.scope("attn.out"):
+        return jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(cdt))
 
 
 def _mla_paged_gather(cache, cfg):
@@ -689,19 +713,23 @@ def _mla_chunk_verify(q_nope, q_rope, cache, ckv, kr, p, cfg, offsets, done):
 def _block(x, bp, cfg, positions, *, moe, cache=None, q_offset=0,
            window=None, slot_positions=None, slot_done=None, plens=None,
            chunk_offsets=None):
+    with tracing.scope("attn.qkv"):
+        xn = apply_norm(x, bp["ln1"], cfg.norm)
     h, new_cache = _attn_forward(
-        apply_norm(x, bp["ln1"], cfg.norm), bp["attn"], cfg, positions,
+        xn, bp["attn"], cfg, positions,
         cache=cache, q_offset=q_offset, window=window,
         slot_positions=slot_positions, slot_done=slot_done, plens=plens,
         chunk_offsets=chunk_offsets)
-    x = x + h
-    hin = apply_norm(x, bp["ln2"], cfg.norm)
-    if moe:
-        h, aux = moe_lib.moe_mlp(hin, bp["moe"], cfg)
-    else:
-        h, aux = ffn_lib.mlp(hin, bp["mlp"], cfg.act), 0.0
-    x = x + h
-    x = annotate(x, ("batch", "seq", "embed"))
+    with tracing.scope("attn.out"):
+        x = x + h
+    with tracing.scope("mlp"):
+        hin = apply_norm(x, bp["ln2"], cfg.norm)
+        if moe:
+            h, aux = moe_lib.moe_mlp(hin, bp["moe"], cfg)
+        else:
+            h, aux = ffn_lib.mlp(hin, bp["mlp"], cfg.act), 0.0
+        x = x + h
+        x = annotate(x, ("batch", "seq", "embed"))
     return x, aux, new_cache
 
 
@@ -741,26 +769,27 @@ def _run_group(x, group, cfg, positions, *, moe, caches=None, q_offset=0,
 
 # ================================================================== forward
 def embed_inputs(params, batch, cfg):
-    cdt = jnp.dtype(cfg.compute_dtype)
-    if cfg.continuous_inputs:
-        x = jnp.einsum("bsi,id->bsd", batch["inputs"].astype(cdt),
-                       params["in_proj"].astype(cdt))
-    else:
-        tokens = batch["tokens"]
-        x = params["embed"].astype(cdt)[tokens]
-    if cfg.scale_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
-    if cfg.head == "cls":
-        cls = jnp.broadcast_to(params["cls_token"].astype(cdt),
-                               (x.shape[0], 1, x.shape[-1]))
-        x = jnp.concatenate([cls, x], axis=1)
-    B, S = x.shape[:2]
-    if cfg.learned_pos:
-        pos = batch.get("positions")
-        if pos is None or pos.ndim != 2:
-            pos = jnp.arange(S, dtype=jnp.int32)[None, :]
-        x = x + params["pos_embed"].astype(cdt)[pos]
-    return annotate(x, ("batch", "seq", "embed"))
+    with tracing.scope("embed"):
+        cdt = jnp.dtype(cfg.compute_dtype)
+        if cfg.continuous_inputs:
+            x = jnp.einsum("bsi,id->bsd", batch["inputs"].astype(cdt),
+                           params["in_proj"].astype(cdt))
+        else:
+            tokens = batch["tokens"]
+            x = params["embed"].astype(cdt)[tokens]
+        if cfg.scale_embeddings:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
+        if cfg.head == "cls":
+            cls = jnp.broadcast_to(params["cls_token"].astype(cdt),
+                                   (x.shape[0], 1, x.shape[-1]))
+            x = jnp.concatenate([cls, x], axis=1)
+        B, S = x.shape[:2]
+        if cfg.learned_pos:
+            pos = batch.get("positions")
+            if pos is None or pos.ndim != 2:
+                pos = jnp.arange(S, dtype=jnp.int32)[None, :]
+            x = x + params["pos_embed"].astype(cdt)[pos]
+        return annotate(x, ("batch", "seq", "embed"))
 
 
 def _positions_from_batch(batch, B, S, cfg, q_offset=0):
@@ -791,7 +820,7 @@ def forward(params, batch, cfg):
         x, aux, _ = _run_group(x, params["moe_blocks"], cfg, positions,
                                moe=True)
         aux_total += aux
-    x = apply_norm(x, params["final_norm"], cfg.norm)
+    x = _final_norm(params, x, cfg)
     aux = {"moe_aux": aux_total}
 
     if cfg.mtp and "mtp" in params and not cfg.continuous_inputs:
@@ -801,15 +830,22 @@ def forward(params, batch, cfg):
     return logits, aux
 
 
+def _final_norm(params, x, cfg):
+    with tracing.scope("head"):
+        return apply_norm(x, params["final_norm"], cfg.norm)
+
+
 def _head(params, x, cfg):
     cdt = x.dtype
     if cfg.head == "none":
         return x
-    if cfg.head == "cls":
-        return jnp.einsum("bd,dc->bc", x[:, 0], params["head"].astype(cdt))
-    w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, w.astype(cdt))
-    return annotate(logits, ("batch", "seq", "vocab"))
+    with tracing.scope("head"):
+        if cfg.head == "cls":
+            return jnp.einsum("bd,dc->bc", x[:, 0],
+                              params["head"].astype(cdt))
+        w = params["embed"].T if cfg.tie_embeddings else params["head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, w.astype(cdt))
+        return annotate(logits, ("batch", "seq", "vocab"))
 
 
 def _mtp_forward(params, h, batch, positions, cfg):
@@ -882,7 +918,7 @@ def _forward_cached(params, batch, cfg, cache, q_offset, plens=None):
                               moe=True, caches=cache["moe"],
                               q_offset=q_offset, plens=plens)
         new_cache["moe"] = nc
-    x = apply_norm(x, params["final_norm"], cfg.norm)
+    x = _final_norm(params, x, cfg)
     return _head(params, x, cfg), new_cache
 
 
@@ -945,7 +981,7 @@ def _forward_cached_slots(params, batch, cfg, cache, slot_positions,
                               slot_positions=slot_positions,
                               slot_done=slot_done)
         new_cache["moe"] = nc
-    x = apply_norm(x, params["final_norm"], cfg.norm)
+    x = _final_norm(params, x, cfg)
     return _head(params, x, cfg), new_cache
 
 
@@ -1002,7 +1038,7 @@ def verify_step_slots(params, tokens, positions, cache, cfg, done=None):
                               moe=True, caches=cache["moe"],
                               chunk_offsets=positions, slot_done=done)
         pending["moe"] = pd
-    x = apply_norm(x, params["final_norm"], cfg.norm)
+    x = _final_norm(params, x, cfg)
     return _head(params, x, cfg), pending
 
 

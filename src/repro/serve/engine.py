@@ -93,6 +93,7 @@ from repro.models import get_family, serve_supported, slot_cache_layout
 from repro.serve import faults as faults_lib
 from repro.serve import paged as paged_lib
 from repro.serve import sampling as sampling_lib
+from repro.serve import tracing
 from repro.serve.speculative import (
     SpeculativeConfig,
     make_draft_prefill,
@@ -108,8 +109,8 @@ POLICIES = ("fifo", "spf")
 # windowed rates (acceptance, tok/s, hit rate) reflect the CURRENT window
 # instead of everything since boot.
 _WINDOW_COUNTERS = (
-    "n_decode_dispatches", "n_decode_steps", "n_prefills", "n_host_syncs",
-    "n_tokens", "n_spec_proposed", "n_spec_accepted", "n_admitted",
+    "n_decode_dispatches", "n_prefills", "n_host_syncs", "n_tokens",
+    "n_spec_proposed", "n_spec_accepted", "n_admitted",
     "n_prefix_hits", "n_prefix_misses", "n_prefix_stalls",
     "n_pages_allocated", "n_expired", "n_quarantined", "n_shed",
     "n_spec_fallbacks", "n_faults_injected", "n_degraded_admissions",
@@ -454,9 +455,7 @@ class ContinuousBatchingEngine:
         self.upgrade_state = "serving"
         self._held_for_upgrade: List[Request] = []
         self.n_upgrades = 0  # completed hot-swaps since boot
-        self.last_upgrade_pause_ms: Optional[float] = None
         self.n_decode_dispatches = 0
-        self.n_decode_steps = 0  # dispatches * k (scan steps executed)
         self.n_prefills = 0  # admission-batch prefill dispatches
         self.n_host_syncs = 0  # blocking device->host reads
         self.n_tokens = 0  # generated tokens (incl. prefill first tokens)
@@ -961,23 +960,30 @@ class ContinuousBatchingEngine:
         grabbed = self._select_admissions(now)
         if not grabbed:
             return
-        if self._paged:
-            pairs = []
-            for i, r in enumerate(grabbed):
-                info = self._alloc_request(r)
-                if info is None:
-                    # page backpressure: wait for the next eviction wave
-                    self.waiting.extendleft(reversed(grabbed[i:]))
-                    break
-                pairs.append((r, info))
-        else:
-            pairs = [(r, None) for r in grabbed]
-        misses = [(r, a) for r, a in pairs if a is None or not a["hit"]]
-        hits = [(r, a) for r, a in pairs if a is not None and a["hit"]]
-        if misses:
-            self._admit_miss_groups(misses)
-        if hits:
-            self._admit_hits(hits)
+        with tracing.span("admit") as sp:
+            if self._paged:
+                pairs = []
+                with tracing.span("admit.alloc"):
+                    for i, r in enumerate(grabbed):
+                        info = self._alloc_request(r)
+                        if info is None:
+                            # page backpressure: wait for the next
+                            # eviction wave
+                            self.waiting.extendleft(reversed(grabbed[i:]))
+                            break
+                        pairs.append((r, info))
+            else:
+                pairs = [(r, None) for r in grabbed]
+            sp.set_metadata(n=len(pairs), uids=[r.uid for r, _ in pairs])
+            misses = [(r, a) for r, a in pairs
+                      if a is None or not a["hit"]]
+            hits = [(r, a) for r, a in pairs
+                    if a is not None and a["hit"]]
+            if misses:
+                self._admit_miss_groups(misses)
+            if hits:
+                with tracing.span("admit.hits", n=len(hits)):
+                    self._admit_hits(hits)
 
     def _admit_miss_groups(self, pairs):
         """The batched-prefill admission path (dense pools, and paged
@@ -989,112 +995,13 @@ class ContinuousBatchingEngine:
         for bucket, group in sorted(groups.items()):
             n = len(group)
             npad = _pow2(n)  # bound (group size, bucket) compile count
-            padded = np.zeros((npad, bucket), np.int32)
-            plens = np.ones((npad,), np.int32)
-            rem0 = np.zeros((npad,), np.int32)
-            eos_new = np.full((npad,), -1, np.int32)
-            # padding rows target the out-of-range slot ``capacity``:
-            # their scatters are dropped entirely (paged pools likewise
-            # pad block-table rows with the out-of-range page sentinel)
-            slots = np.full((npad,), self.capacity, np.int32)
-            bt_rows = [None if m is None else
-                       np.full((npad, m.nblk), m.sentinel, np.int32)
-                       for m in self._metas]
-            for j, (r, a) in enumerate(group):
-                plens[j] = len(r.prompt)
-                padded[j, :plens[j]] = r.prompt
-                # a resume's committed run is part of its prompt and
-                # already spent that much budget
-                rem0[j] = r.max_new_tokens - r.n_committed - 1
-                eos_new[j] = -1 if r.eos_id is None else r.eos_id
-                slots[j] = self.free.pop()
-                if a is not None:
-                    pids = a["pids"]
-                    self._slot_pages[int(slots[j])] = pids
-                    for pi, m in enumerate(self._metas):
-                        if m is not None:
-                            cnt = min(len(pids), m.nblk)
-                            bt_rows[pi][j, :cnt] = pids[:cnt]
-            rows = [self.fam.init_cache(self.cfg, npad, self.max_len)]
-            # pad-tail cache entries are garbage but never visible: each
-            # decode step overwrites its own position before the per-row
-            # length mask reaches it
-            if self.sampling is None:
-                first, rows[0] = self._prefill(
-                    self.params, jnp.asarray(padded), jnp.asarray(plens),
-                    rows[0])
-                keys_dev = jnp.zeros((npad, 2), jnp.uint32)
-            else:
-                # chain roots are derived from (seed, uid) ON DEVICE in
-                # the same prefill dispatch — no key round-trip/sync;
-                # ``skips`` replays a resume's committed-run chain splits
-                # so its first fresh sample draws from the same chain
-                # position as the uninterrupted run
-                uids = np.zeros((npad,), np.int32)
-                uids[:n] = [r.uid for r, _ in group]
-                skips = np.zeros((npad,), np.int32)
-                skips[:n] = [r.n_committed for r, _ in group]
-                first, rows[0], keys_dev = self._prefill(
-                    self.params, jnp.asarray(padded), jnp.asarray(plens),
-                    rows[0], jnp.asarray(uids), jnp.asarray(skips))
-            if self.speculative is not None:
-                # the draft pool admits the SAME prompt rows: its per-row
-                # state after the real prompt, first token comes from the
-                # target
-                draft_rows = get_family(self.speculative.cfg).init_cache(
-                    self.speculative.cfg, npad, self.max_len)
-                rows.append(self._draft_prefill(
-                    self.speculative.params, jnp.asarray(padded),
-                    jnp.asarray(plens), draft_rows))
-                self.n_prefills += 1
-            if (self._windowed and self._prefix_ok
-                    and not self._arena_degraded
-                    and self._reg_copy is not None):
-                # ring prefix cache: the admit scatter is about to write
-                # RING-wrapped pages, which the donor will keep
-                # overwriting — so copy the prompt's last intact full
-                # pages out of the prefill scratch into registry-only
-                # pages first (best-effort: an admission proceeds fine
-                # without registering, it just can't donate hits)
-                meta = self._metas[0]
-                reg_pids = np.full((npad, meta.nblk), meta.sentinel,
-                                   np.int32)
-                reg_blk = np.zeros((npad, meta.nblk), np.int32)
-                reg_records = []
-                for j, (r, a) in enumerate(group):
-                    if a is None or not a["digests"]:
-                        continue
-                    share = (len(r.prompt) - 1) // meta.page
-                    nreg = min(share, meta.nblk - 1)
-                    if nreg <= 0:
-                        continue
-                    got = self._alloc.alloc(nreg)
-                    if got is None:
-                        continue
-                    for t, ab in enumerate(range(share - nreg, share)):
-                        reg_pids[j, t] = got[t]
-                        reg_blk[j, t] = ab % meta.nblk
-                    reg_records.append(
-                        (a["digests"][share - nreg:share], got))
-                if reg_records:
-                    pool0 = self._reg_copy(
-                        self._pools[0], rows[0], jnp.asarray(reg_pids),
-                        jnp.asarray(reg_blk))
-                    self._pools = (pool0,) + self._pools[1:]
-                    for dg, got in reg_records:
-                        self._alloc.register(dg, got)
-                        # registered pages retire to the LRU with their
-                        # bytes intact; a first-writer-wins loser comes
-                        # back on the zero list and is freed
-                        self._zero_pending.extend(self._alloc.release(got))
-            self._pools, self._state = self._admit(
-                self._pools, tuple(rows), self._state, jnp.asarray(slots),
-                tuple(None if b is None else jnp.asarray(b)
-                      for b in bt_rows),
-                first, jnp.asarray(plens), jnp.asarray(rem0),
-                jnp.asarray(eos_new), keys_dev)
+            with tracing.span("admit.prefill", bucket=bucket, n=n,
+                              npad=npad):
+                slots, plens, first = self._prefill_group(bucket, group,
+                                                          npad)
             self.n_prefills += 1
-            first_host = np.asarray(first)
+            with tracing.span("admit.sync"):
+                first_host = np.asarray(first)
             self.n_host_syncs += 1
             t = time.monotonic()
             for j, (r, a) in enumerate(group):
@@ -1125,7 +1032,117 @@ class ContinuousBatchingEngine:
                 self._finish_if_done(seq, seq.tokens[-1])
             if self.journal is not None:
                 # ride the admission host sync that just happened
-                self.journal.flush()
+                self._flush_journal()
+
+    def _prefill_group(self, bucket, group, npad):
+        """Enqueue one bucket group's prefill and its pool/state
+        scatter (no host sync).  Returns (slots, plens, first tokens)."""
+        n = len(group)
+        padded = np.zeros((npad, bucket), np.int32)
+        plens = np.ones((npad,), np.int32)
+        rem0 = np.zeros((npad,), np.int32)
+        eos_new = np.full((npad,), -1, np.int32)
+        # padding rows target the out-of-range slot ``capacity``:
+        # their scatters are dropped entirely (paged pools likewise
+        # pad block-table rows with the out-of-range page sentinel)
+        slots = np.full((npad,), self.capacity, np.int32)
+        bt_rows = [None if m is None else
+                   np.full((npad, m.nblk), m.sentinel, np.int32)
+                   for m in self._metas]
+        for j, (r, a) in enumerate(group):
+            plens[j] = len(r.prompt)
+            padded[j, :plens[j]] = r.prompt
+            # a resume's committed run is part of its prompt and
+            # already spent that much budget
+            rem0[j] = r.max_new_tokens - r.n_committed - 1
+            eos_new[j] = -1 if r.eos_id is None else r.eos_id
+            slots[j] = self.free.pop()
+            if a is not None:
+                pids = a["pids"]
+                self._slot_pages[int(slots[j])] = pids
+                for pi, m in enumerate(self._metas):
+                    if m is not None:
+                        cnt = min(len(pids), m.nblk)
+                        bt_rows[pi][j, :cnt] = pids[:cnt]
+        rows = [self.fam.init_cache(self.cfg, npad, self.max_len)]
+        # pad-tail cache entries are garbage but never visible: each
+        # decode step overwrites its own position before the per-row
+        # length mask reaches it
+        if self.sampling is None:
+            first, rows[0] = self._prefill(
+                self.params, jnp.asarray(padded), jnp.asarray(plens),
+                rows[0])
+            keys_dev = jnp.zeros((npad, 2), jnp.uint32)
+        else:
+            # chain roots are derived from (seed, uid) ON DEVICE in
+            # the same prefill dispatch — no key round-trip/sync;
+            # ``skips`` replays a resume's committed-run chain splits
+            # so its first fresh sample draws from the same chain
+            # position as the uninterrupted run
+            uids = np.zeros((npad,), np.int32)
+            uids[:n] = [r.uid for r, _ in group]
+            skips = np.zeros((npad,), np.int32)
+            skips[:n] = [r.n_committed for r, _ in group]
+            first, rows[0], keys_dev = self._prefill(
+                self.params, jnp.asarray(padded), jnp.asarray(plens),
+                rows[0], jnp.asarray(uids), jnp.asarray(skips))
+        if self.speculative is not None:
+            # the draft pool admits the SAME prompt rows: its per-row
+            # state after the real prompt, first token comes from the
+            # target
+            draft_rows = get_family(self.speculative.cfg).init_cache(
+                self.speculative.cfg, npad, self.max_len)
+            rows.append(self._draft_prefill(
+                self.speculative.params, jnp.asarray(padded),
+                jnp.asarray(plens), draft_rows))
+            self.n_prefills += 1
+        if (self._windowed and self._prefix_ok
+                and not self._arena_degraded
+                and self._reg_copy is not None):
+            # ring prefix cache: the admit scatter is about to write
+            # RING-wrapped pages, which the donor will keep
+            # overwriting — so copy the prompt's last intact full
+            # pages out of the prefill scratch into registry-only
+            # pages first (best-effort: an admission proceeds fine
+            # without registering, it just can't donate hits)
+            meta = self._metas[0]
+            reg_pids = np.full((npad, meta.nblk), meta.sentinel,
+                               np.int32)
+            reg_blk = np.zeros((npad, meta.nblk), np.int32)
+            reg_records = []
+            for j, (r, a) in enumerate(group):
+                if a is None or not a["digests"]:
+                    continue
+                share = (len(r.prompt) - 1) // meta.page
+                nreg = min(share, meta.nblk - 1)
+                if nreg <= 0:
+                    continue
+                got = self._alloc.alloc(nreg)
+                if got is None:
+                    continue
+                for t, ab in enumerate(range(share - nreg, share)):
+                    reg_pids[j, t] = got[t]
+                    reg_blk[j, t] = ab % meta.nblk
+                reg_records.append(
+                    (a["digests"][share - nreg:share], got))
+            if reg_records:
+                pool0 = self._reg_copy(
+                    self._pools[0], rows[0], jnp.asarray(reg_pids),
+                    jnp.asarray(reg_blk))
+                self._pools = (pool0,) + self._pools[1:]
+                for dg, got in reg_records:
+                    self._alloc.register(dg, got)
+                    # registered pages retire to the LRU with their
+                    # bytes intact; a first-writer-wins loser comes
+                    # back on the zero list and is freed
+                    self._zero_pending.extend(self._alloc.release(got))
+        self._pools, self._state = self._admit(
+            self._pools, tuple(rows), self._state, jnp.asarray(slots),
+            tuple(None if b is None else jnp.asarray(b)
+                  for b in bt_rows),
+            first, jnp.asarray(plens), jnp.asarray(rem0),
+            jnp.asarray(eos_new), keys_dev)
+        return slots, plens, first
 
     def _admit_hits(self, pairs):
         """No-prefill admission: point the slots' leading block-table
@@ -1189,7 +1206,8 @@ class ContinuousBatchingEngine:
                 if a["resident"]:
                     self._zero_pending.extend(
                         self._alloc.release(a["resident"]))
-        first_host = np.asarray(first)  # capacity-wide: index by slot
+        with tracing.span("admit.sync"):
+            first_host = np.asarray(first)  # capacity-wide: index by slot
         self.n_host_syncs += 1
         t = time.monotonic()
         for j, (r, a) in enumerate(pairs):
@@ -1207,6 +1225,10 @@ class ContinuousBatchingEngine:
                 self.journal.record_tokens(r.uid, [int(first_host[slot])])
             self._finish_if_done(seq, seq.tokens[-1])
         if self.journal is not None:
+            self._flush_journal()
+
+    def _flush_journal(self):
+        with tracing.span("journal.flush"):
             self.journal.flush()
 
     # ------------------------------------------------------------- lifecycle
@@ -1324,48 +1346,49 @@ class ContinuousBatchingEngine:
         if not self._evict_pending and not (self._paged
                                             and self._zero_pending):
             return
-        zero = list(self._zero_pending)
-        self._zero_pending.clear()
-        for slot in self._evict_pending:
-            pids = self._slot_pages.pop(slot, None)
-            if pids:
-                # one reference per namespace was taken at admission; a
-                # page crosses GLOBAL zero during exactly one of these
-                # releases and must then be zeroed in EVERY paged pool
-                # (it is a row in each pool's arenas)
-                for ns in self._ns_of.values():
-                    zero.extend(self._alloc.release(pids, ns=ns))
-        slots = np.full((self.capacity,), self.capacity, np.int32)
-        slots[:len(self._evict_pending)] = self._evict_pending
-        if not self._paged:
-            self._pools, self._state = self._evict(
-                self._pools, self._state, jnp.asarray(slots),
-                (None,) * len(self._pools))
-        else:
-            # fixed zero-list shape (capacity * max nblk, shared by all
-            # paged pools) bounds the compile count; overflow (possible
-            # after alloc rollbacks) loops — the slot scatter is
-            # idempotent
-            lim = self.capacity * max(m.nblk for m in self._metas
-                                      if m is not None)
-            while True:
-                take = zero[:lim]
-                del zero[:lim]
-                chunk = []
-                for m in self._metas:
-                    if m is None:
-                        chunk.append(None)
-                        continue
-                    zp = np.full((lim,), m.sentinel, np.int32)
-                    zp[:len(take)] = take
-                    chunk.append(jnp.asarray(zp))
+        with tracing.span("evict", n=len(self._evict_pending)):
+            zero = list(self._zero_pending)
+            self._zero_pending.clear()
+            for slot in self._evict_pending:
+                pids = self._slot_pages.pop(slot, None)
+                if pids:
+                    # one reference per namespace was taken at admission; a
+                    # page crosses GLOBAL zero during exactly one of these
+                    # releases and must then be zeroed in EVERY paged pool
+                    # (it is a row in each pool's arenas)
+                    for ns in self._ns_of.values():
+                        zero.extend(self._alloc.release(pids, ns=ns))
+            slots = np.full((self.capacity,), self.capacity, np.int32)
+            slots[:len(self._evict_pending)] = self._evict_pending
+            if not self._paged:
                 self._pools, self._state = self._evict(
                     self._pools, self._state, jnp.asarray(slots),
-                    tuple(chunk))
-                if not zero:
-                    break
-        self.free.extend(self._evict_pending)
-        self._evict_pending.clear()
+                    (None,) * len(self._pools))
+            else:
+                # fixed zero-list shape (capacity * max nblk, shared by all
+                # paged pools) bounds the compile count; overflow (possible
+                # after alloc rollbacks) loops — the slot scatter is
+                # idempotent
+                lim = self.capacity * max(m.nblk for m in self._metas
+                                          if m is not None)
+                while True:
+                    take = zero[:lim]
+                    del zero[:lim]
+                    chunk = []
+                    for m in self._metas:
+                        if m is None:
+                            chunk.append(None)
+                            continue
+                        zp = np.full((lim,), m.sentinel, np.int32)
+                        zp[:len(take)] = take
+                        chunk.append(jnp.asarray(zp))
+                    self._pools, self._state = self._evict(
+                        self._pools, self._state, jnp.asarray(slots),
+                        tuple(chunk))
+                    if not zero:
+                        break
+            self.free.extend(self._evict_pending)
+            self._evict_pending.clear()
 
     # ----------------------------------------------------------- live upgrade
     def _apply_upgrade(self, mgr) -> None:
@@ -1384,80 +1407,80 @@ class ContinuousBatchingEngine:
         Zero requests are dropped: a resume's position and page need
         equal its original request's, so it is admissible by
         construction."""
-        t0 = time.perf_counter()
-        self.upgrade_state = "relayout"
-        while self._inflight:
-            self._process(self._inflight.popleft())
-        self._flush_evictions()
-        # page-residency delta: pages live at quiesce are all LOST by the
-        # swap — cache rows are internal activations of the OLD function
-        # (grown params + re-laid geometry invalidate every byte), so
-        # "carried" is structurally zero and the visible cost of a live
-        # upgrade is the re-prefill page bill of the resume wave.
-        pages_at_swap = self.pages_in_use
-        resumes: List[Request] = []
-        for seq in sorted(self.active.values(),
-                          key=lambda s: (s.t_first, s.req.uid)):
-            r = seq.req
-            orig = (r.prompt[:len(r.prompt) - r.n_committed]
-                    if r.n_committed else r.prompt)
-            resumes.append(Request(
-                uid=r.uid,
-                prompt=np.asarray(list(orig) + seq.tokens, np.int32),
-                max_new_tokens=r.max_new_tokens, eos_id=r.eos_id,
-                arrival=r.arrival, deadline=r.deadline,
-                n_committed=len(seq.tokens)))
-        self.active.clear()
-        self._evict_pending.clear()
-        spec = mgr.spec_config()
-        self._configure(mgr.cfg_tgt, mgr.grown_params, spec)
-        if spec is not None and any(self._invalid_reason(r) is not None
-                                    for r in resumes):
-            # enabling the post-swap draft raised the shared-arena page
-            # need (a request reserves max(need) across pools) above an
-            # explicit --pages budget for an in-flight resume; zero-drop
-            # beats free speculation, so swap without the draft
-            mgr.disable_spec("draft page need exceeds the shared arena "
-                             "for an in-flight request")
-            self._configure(mgr.cfg_tgt, mgr.grown_params, None)
-        # queued (never-admitted) requests were validated under the OLD
-        # geometry; re-validate so one that became unservable cannot
-        # livelock admission.  Mid-flight resumes skip this by design.
-        keep: collections.deque = collections.deque()
-        for r in self.waiting:
-            why = self._invalid_reason(r)
-            if why is None:
-                keep.append(r)
-            else:
-                self._seen_uids.discard(r.uid)
-                self._reject(r.uid,
-                             f"request {r.uid}: {why} "
-                             "(post-upgrade geometry)")
-        keep.extendleft(reversed(resumes))
-        self.waiting = keep
-        self.n_upgrades += 1
-        self.upgrade_state = "swapped"
-        held, self._held_for_upgrade = self._held_for_upgrade, []
-        for r in held:
-            self._submit_checked(r)
-        if self.journal is not None:
-            # last-submit-wins resume records: a crash right after the
-            # swap replays exactly these prompt‖committed requests
-            for r in resumes:
-                self.journal.record_submit(r)
-            self.journal.flush()
-        pause_ms = (time.perf_counter() - t0) * 1e3
-        self.last_upgrade_pause_ms = pause_ms
-        pages_reprefill = 0
-        if self._alloc is not None:
-            pages_reprefill = sum(
-                max(paged_lib.pages_needed(len(r.prompt),
-                                           r.max_new_tokens, m)
-                    for m in self._metas if m is not None)
-                for r in resumes)
-        mgr._swapped(self, pause_ms, resumes,
-                     pages_resident=pages_at_swap,
-                     pages_reprefilled=pages_reprefill)
+        with tracing.span("upgrade.swap"):
+            t0 = time.perf_counter()
+            self.upgrade_state = "relayout"
+            while self._inflight:
+                self._process(self._inflight.popleft())
+            self._flush_evictions()
+            # page-residency delta: pages live at quiesce are all LOST by the
+            # swap — cache rows are internal activations of the OLD function
+            # (grown params + re-laid geometry invalidate every byte), so
+            # "carried" is structurally zero and the visible cost of a live
+            # upgrade is the re-prefill page bill of the resume wave.
+            pages_at_swap = self.pages_in_use
+            resumes: List[Request] = []
+            for seq in sorted(self.active.values(),
+                              key=lambda s: (s.t_first, s.req.uid)):
+                r = seq.req
+                orig = (r.prompt[:len(r.prompt) - r.n_committed]
+                        if r.n_committed else r.prompt)
+                resumes.append(Request(
+                    uid=r.uid,
+                    prompt=np.asarray(list(orig) + seq.tokens, np.int32),
+                    max_new_tokens=r.max_new_tokens, eos_id=r.eos_id,
+                    arrival=r.arrival, deadline=r.deadline,
+                    n_committed=len(seq.tokens)))
+            self.active.clear()
+            self._evict_pending.clear()
+            spec = mgr.spec_config()
+            self._configure(mgr.cfg_tgt, mgr.grown_params, spec)
+            if spec is not None and any(self._invalid_reason(r) is not None
+                                        for r in resumes):
+                # enabling the post-swap draft raised the shared-arena page
+                # need (a request reserves max(need) across pools) above an
+                # explicit --pages budget for an in-flight resume; zero-drop
+                # beats free speculation, so swap without the draft
+                mgr.disable_spec("draft page need exceeds the shared arena "
+                                 "for an in-flight request")
+                self._configure(mgr.cfg_tgt, mgr.grown_params, None)
+            # queued (never-admitted) requests were validated under the OLD
+            # geometry; re-validate so one that became unservable cannot
+            # livelock admission.  Mid-flight resumes skip this by design.
+            keep: collections.deque = collections.deque()
+            for r in self.waiting:
+                why = self._invalid_reason(r)
+                if why is None:
+                    keep.append(r)
+                else:
+                    self._seen_uids.discard(r.uid)
+                    self._reject(r.uid,
+                                 f"request {r.uid}: {why} "
+                                 "(post-upgrade geometry)")
+            keep.extendleft(reversed(resumes))
+            self.waiting = keep
+            self.n_upgrades += 1
+            self.upgrade_state = "swapped"
+            held, self._held_for_upgrade = self._held_for_upgrade, []
+            for r in held:
+                self._submit_checked(r)
+            if self.journal is not None:
+                # last-submit-wins resume records: a crash right after the
+                # swap replays exactly these prompt‖committed requests
+                for r in resumes:
+                    self.journal.record_submit(r)
+                self._flush_journal()
+            pause_ms = (time.perf_counter() - t0) * 1e3
+            pages_reprefill = 0
+            if self._alloc is not None:
+                pages_reprefill = sum(
+                    max(paged_lib.pages_needed(len(r.prompt),
+                                               r.max_new_tokens, m)
+                        for m in self._metas if m is not None)
+                    for r in resumes)
+            mgr._swapped(self, pause_ms, resumes,
+                         pages_resident=pages_at_swap,
+                         pages_reprefilled=pages_reprefill)
 
     # ---------------------------------------------------------------- faults
     def _inject(self, f):
@@ -1468,7 +1491,7 @@ class ContinuousBatchingEngine:
             # kill -9 at a step boundary: journaled state survives,
             # unread in-flight blocks do not
             if self.journal is not None:
-                self.journal.flush()
+                self._flush_journal()
             raise faults_lib.EngineKilled(
                 f"injected crash at engine step {self._fault_step}")
         if f.kind in ("slow", "hang"):
@@ -1510,97 +1533,102 @@ class ContinuousBatchingEngine:
     def _dispatch(self):
         """Launch one on-device macro step (K decode steps — or K whole
         speculative draft→verify→commit blocks — with no sync)."""
-        if self.faults is not None:
-            self._fault_step += 1
-            for f in self.faults.due(self._fault_step):
-                self._inject(f)
-        tokens, positions, remaining, eos_ids, done, keys = self._state
-        stats = None
-        dbad = None
-        if self.speculative is not None and not self._spec_fallback:
-            (block, valid, poison, dbad, tokens, positions, remaining,
-             done, pool_t, pool_d, keys, n_prop, n_acc) = self._loop(
-                self.params, self.speculative.params, tokens, positions,
-                remaining, eos_ids, done, self._pools[0], self._pools[1],
-                keys)
-            self._pools = (pool_t, pool_d)
-            stats = (n_prop, n_acc)
-        else:
-            # _fb_loop: a speculative engine whose draft misbehaved keeps
-            # serving through the plain macro loop on its TARGET pool
-            loop = self._fb_loop if self._spec_fallback else self._loop
-            if self.sampling is not None:
-                (block, valid, poison, tokens, positions, remaining, done,
-                 pool, keys) = loop(self.params, tokens, positions,
-                                    remaining, eos_ids, done,
-                                    self._pools[0], keys)
+        with tracing.span("dispatch"):
+            if self.faults is not None:
+                self._fault_step += 1
+                for f in self.faults.due(self._fault_step):
+                    self._inject(f)
+            tokens, positions, remaining, eos_ids, done, keys = self._state
+            stats = None
+            dbad = None
+            if self.speculative is not None and not self._spec_fallback:
+                (block, valid, poison, dbad, tokens, positions, remaining,
+                 done, pool_t, pool_d, keys, n_prop, n_acc) = self._loop(
+                    self.params, self.speculative.params, tokens, positions,
+                    remaining, eos_ids, done, self._pools[0], self._pools[1],
+                    keys)
+                self._pools = (pool_t, pool_d)
+                stats = (n_prop, n_acc)
             else:
-                (block, valid, poison, tokens, positions, remaining, done,
-                 pool) = loop(self.params, tokens, positions, remaining,
-                              eos_ids, done, self._pools[0])
-            self._pools = (pool,) + self._pools[1:]
-        self._state = (tokens, positions, remaining, eos_ids, done, keys)
-        self.n_decode_dispatches += 1
-        self.n_decode_steps += self.k
-        live = [(slot, seq.req.uid) for slot, seq in self.active.items()]
-        self._inflight.append((block, valid, poison, dbad, live, stats))
+                # _fb_loop: a speculative engine whose draft misbehaved keeps
+                # serving through the plain macro loop on its TARGET pool
+                loop = self._fb_loop if self._spec_fallback else self._loop
+                if self.sampling is not None:
+                    (block, valid, poison, tokens, positions, remaining, done,
+                     pool, keys) = loop(self.params, tokens, positions,
+                                        remaining, eos_ids, done,
+                                        self._pools[0], keys)
+                else:
+                    (block, valid, poison, tokens, positions, remaining, done,
+                     pool) = loop(self.params, tokens, positions, remaining,
+                                  eos_ids, done, self._pools[0])
+                self._pools = (pool,) + self._pools[1:]
+            self._state = (tokens, positions, remaining, eos_ids, done, keys)
+            self.n_decode_dispatches += 1
+            live = [(slot, seq.req.uid) for slot, seq in self.active.items()]
+            self._inflight.append((block, valid, poison, dbad, live, stats))
 
     def _process(self, item):
         """Block on one macro step's token block (the single host sync per
         dispatch) and advance the host-side sequence records.  The
         NaN/Inf sentinels and the journal's committed-token deltas ride
         this same readback — fault tolerance adds no host sync."""
-        block, valid, poison, dbad, live, stats = item
-        block, valid, poison, dbad, stats = jax.device_get(
-            (block, valid, poison, dbad, stats))
-        self.n_host_syncs += 1
-        if stats is not None:
-            # acceptance telemetry rides the same readback — no extra sync
-            self.n_spec_proposed += int(stats[0])
-            self.n_spec_accepted += int(stats[1])
-        for slot, uid in live:
-            seq = self.active.get(slot)
-            if seq is None or seq.req.uid != uid:
-                # the slot was retired (and possibly re-admitted) while this
-                # block was in flight; its rows were device-done, so the
-                # valid mask is all False for it anyway
-                continue
-            vm = valid[:, slot]
-            nv = int(vm.sum())
-            if nv:
-                new = [int(t) for t in block[:, slot][vm]]
-                seq.pos += nv
-                seq.tokens.extend(new)
-                self.n_tokens += nv
-                if self.journal is not None:
-                    self.journal.record_tokens(uid, new)
-                self._finish_if_done(seq, seq.tokens[-1])
-            if bool(poison[slot]) and self.active.get(slot) is seq:
-                # the row froze itself at the bad step (nothing from it
-                # was committed); evict it with an explicit outcome
-                self._quarantine(seq)
-        if dbad is not None and bool(dbad) and not self._spec_fallback:
-            # degradation ladder: draft logits went non-finite — keep
-            # serving every request through the plain target-only loop
-            self._spec_fallback = True
-            self.n_spec_fallbacks += 1
-        if self.journal is not None:
-            self.journal.flush()
+        with tracing.span("readback"):
+            block, valid, poison, dbad, live, stats = item
+            with tracing.span("readback.wait"):
+                block, valid, poison, dbad, stats = jax.device_get(
+                    (block, valid, poison, dbad, stats))
+            self.n_host_syncs += 1
+            if stats is not None:
+                # acceptance telemetry rides the same readback — no extra
+                # sync
+                self.n_spec_proposed += int(stats[0])
+                self.n_spec_accepted += int(stats[1])
+            for slot, uid in live:
+                seq = self.active.get(slot)
+                if seq is None or seq.req.uid != uid:
+                    # the slot was retired (and possibly re-admitted) while
+                    # this block was in flight; its rows were device-done,
+                    # so the valid mask is all False for it anyway
+                    continue
+                vm = valid[:, slot]
+                nv = int(vm.sum())
+                if nv:
+                    new = [int(t) for t in block[:, slot][vm]]
+                    seq.pos += nv
+                    seq.tokens.extend(new)
+                    self.n_tokens += nv
+                    if self.journal is not None:
+                        self.journal.record_tokens(uid, new)
+                    self._finish_if_done(seq, seq.tokens[-1])
+                if bool(poison[slot]) and self.active.get(slot) is seq:
+                    # the row froze itself at the bad step (nothing from it
+                    # was committed); evict it with an explicit outcome
+                    self._quarantine(seq)
+            if dbad is not None and bool(dbad) and not self._spec_fallback:
+                # degradation ladder: draft logits went non-finite — keep
+                # serving every request through the plain target-only loop
+                self._spec_fallback = True
+                self.n_spec_fallbacks += 1
+            if self.journal is not None:
+                self._flush_journal()
 
     def step(self, now: Optional[float] = None):
         """One synchronous engine iteration: expire, evict, admit arrived
         requests into free slots, run one macro step, and read it back."""
-        if self.upgrade is not None:
-            self.upgrade.poll(self)
-        self._expire(now)
-        self._flush_evictions()
-        self._admit_batch(now)
-        if not self.active and not self._inflight:
-            return
-        if self.active:
-            self._dispatch()
-        while self._inflight:
-            self._process(self._inflight.popleft())
+        with tracing.span("step", active=len(self.active),
+                          waiting=len(self.waiting)):
+            if self.upgrade is not None:
+                self.upgrade.poll(self)
+            self._expire(now)
+            self._flush_evictions()
+            self._admit_batch(now)
+            if not self.active and not self._inflight:
+                return
+            if self.active:
+                self._dispatch()
+            while self._inflight:
+                self._process(self._inflight.popleft())
 
     def run(self, requests=None, *, realtime: bool = False,
             pipeline: bool = True):
@@ -1635,29 +1663,34 @@ class ContinuousBatchingEngine:
                 if realtime and not self.active and self.waiting:
                     nxt = min(r.arrival for r in self.waiting)
                     if nxt > now:
-                        time.sleep(nxt - now)
+                        with tracing.span("sleep"):
+                            time.sleep(nxt - now)
                         now = wall_now()
                 self.step(now=now)
         else:
             while self.waiting or self.active or self._inflight:
-                now = wall_now()
-                if (realtime and not self.active and not self._inflight
-                        and self.waiting):
-                    nxt = min(r.arrival for r in self.waiting)
-                    if nxt > now:
-                        time.sleep(nxt - now)
-                        now = wall_now()
-                if self.upgrade is not None:
-                    self.upgrade.poll(self)
-                self._expire(now)
-                self._flush_evictions()
-                self._admit_batch(now)
-                if self.active:
-                    self._dispatch()
-                # block on the OLDEST in-flight block only once a newer one
-                # is already dispatched (or nothing is left to dispatch)
-                if len(self._inflight) >= (2 if self.active else 1):
-                    self._process(self._inflight.popleft())
+                with tracing.span("step", active=len(self.active),
+                                  waiting=len(self.waiting)):
+                    now = wall_now()
+                    if (realtime and not self.active
+                            and not self._inflight and self.waiting):
+                        nxt = min(r.arrival for r in self.waiting)
+                        if nxt > now:
+                            with tracing.span("sleep"):
+                                time.sleep(nxt - now)
+                            now = wall_now()
+                    if self.upgrade is not None:
+                        self.upgrade.poll(self)
+                    self._expire(now)
+                    self._flush_evictions()
+                    self._admit_batch(now)
+                    if self.active:
+                        self._dispatch()
+                    # block on the OLDEST in-flight block only once a
+                    # newer one is already dispatched (or nothing is left
+                    # to dispatch)
+                    if len(self._inflight) >= (2 if self.active else 1):
+                        self._process(self._inflight.popleft())
         self._flush_evictions()
         return {uid: toks for uid, toks in self.finished.items()
                 if uid not in already}

@@ -47,6 +47,7 @@ import jax
 import numpy as np
 
 from repro.models import get_family, serve_supported
+from repro.serve import tracing
 from repro.serve.engine import ContinuousBatchingEngine, Request
 from repro.serve.speculative import SpeculativeConfig, spec_pair_supported
 
@@ -261,12 +262,14 @@ class UpgradeManager:
                     from repro.data.synthetic import lm_data_iter
                     data_iter = lm_data_iter(self.cfg_tgt.vocab_size, 4, 32,
                                              seed=self.seed + 1)
-                self.grown_params = grow_from_source(
-                    self.cfg_src, self.cfg_tgt, method=self.method,
-                    rank=self.rank, steps=self.grow_steps,
-                    data_iter=data_iter, params_src=self.params_src,
-                    rng=jax.random.PRNGKey(self.seed),
-                    noise=self.grow_noise, log_fn=lambda *a, **k: None)
+                with tracing.span("upgrade.grow", method=self.method,
+                                  steps=self.grow_steps):
+                    self.grown_params = grow_from_source(
+                        self.cfg_src, self.cfg_tgt, method=self.method,
+                        rank=self.rank, steps=self.grow_steps,
+                        data_iter=data_iter, params_src=self.params_src,
+                        rng=jax.random.PRNGKey(self.seed),
+                        noise=self.grow_noise, log_fn=lambda *a, **k: None)
             self.grow_seconds = time.monotonic() - t0
             if self.probe_fp:
                 rng = np.random.default_rng(self.seed)
@@ -276,7 +279,8 @@ class UpgradeManager:
                     self.cfg_src, self.params_src, self.cfg_tgt,
                     self.grown_params, prompts)
             if self.prewarm:
-                self._prewarm()
+                with tracing.span("upgrade.prewarm"):
+                    self._prewarm()
             self._set_state("ready")
             self._ready.set()
         except BaseException as e:  # noqa: BLE001 — surfaced via .error

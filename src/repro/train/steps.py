@@ -264,6 +264,7 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
     slot placement and interleaving.
     """
     from repro.serve import sampling as sampling_lib
+    from repro.serve import tracing
 
     fam = get_family(cfg)
     if not hasattr(fam, "decode_step_slots"):
@@ -279,25 +280,26 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
         live = ~done
         logits, cache = fam.decode_step_slots(
             params, tokens, positions, cache, cfg, done=done)
-        # NaN/Inf sentinel: a poisoned live row freezes HERE — its token
-        # is never committed and (crucially, for recurrent state) no
-        # further update runs on the row.  The elementwise reduce fuses
-        # into the dispatch; nothing extra crosses to the host.
-        bad = live & ~jnp.all(jnp.isfinite(
-            logits.astype(jnp.float32)), axis=-1)
-        live = live & ~bad
-        poison = poison | bad
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            keys_new, subs = sampling_lib.next_keys(keys)
-            keys = jnp.where(live[:, None], keys_new, keys)
-            nxt = sampling_lib.sample_logits(logits, subs, sampling)
-        tokens = jnp.where(live, nxt, tokens)
-        remaining = jnp.where(live, remaining - 1, remaining)
-        done = done | bad | (live & ((tokens == eos_ids)
-                                     | (remaining <= 0)))
-        positions = jnp.where(live, positions + 1, positions)
+        with tracing.scope("select"):
+            # NaN/Inf sentinel: a poisoned live row freezes HERE — its token
+            # is never committed and (crucially, for recurrent state) no
+            # further update runs on the row.  The elementwise reduce fuses
+            # into the dispatch; nothing extra crosses to the host.
+            bad = live & ~jnp.all(jnp.isfinite(
+                logits.astype(jnp.float32)), axis=-1)
+            live = live & ~bad
+            poison = poison | bad
+            if greedy:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                keys_new, subs = sampling_lib.next_keys(keys)
+                keys = jnp.where(live[:, None], keys_new, keys)
+                nxt = sampling_lib.sample_logits(logits, subs, sampling)
+            tokens = jnp.where(live, nxt, tokens)
+            remaining = jnp.where(live, remaining - 1, remaining)
+            done = done | bad | (live & ((tokens == eos_ids)
+                                         | (remaining <= 0)))
+            positions = jnp.where(live, positions + 1, positions)
         carry = (tokens, positions, remaining, done, poison, cache) \
             if greedy \
             else (tokens, positions, remaining, done, poison, cache, keys)
