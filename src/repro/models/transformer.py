@@ -733,24 +733,59 @@ def _block(x, bp, cfg, positions, *, moe, cache=None, q_offset=0,
     return x, aux, new_cache
 
 
+def _layer_block_table(bt, layer, n_pages, n_layers):
+    """Layer ``layer``'s block table into the layer-flattened arena
+    ``(n_layers * n_pages, page, ...)``: live entries shift onto that
+    layer's pages; the sentinel ``n_pages`` (never-allocated blocks) maps
+    to the flat arena's own sentinel ``n_layers * n_pages`` — a plain
+    shift would land it on layer ``layer + 1``'s first page, and the ring
+    path WRITES through sentinel entries (dropped only when out of
+    range)."""
+    return jnp.where(bt < n_pages, bt + layer * n_pages, n_layers * n_pages)
+
+
 def _run_group(x, group, cfg, positions, *, moe, caches=None, q_offset=0,
                slot_positions=None, slot_done=None, plens=None,
                chunk_offsets=None):
-    """Scan a stacked block group. caches: stacked (n, ...) or None."""
+    """Scan a stacked block group. caches: stacked (n, ...) or None.
+
+    Slot decode over a paged k/v group carries the arenas whole through
+    the scan, viewed as one layer-flattened arena ``(n * n_pages, ...)``,
+    and scans only the block table: each layer scatters its row into the
+    carried arena in place and reads its pages through a per-layer
+    offset table (``_layer_block_table``).  Scanning the arenas as
+    ``xs``/``ys`` would slice out and re-stack every layer's arena on
+    every decode step instead.
+    """
+    arena = None
+    if slot_positions is not None and caches is not None \
+            and set(caches) == {"k", "v", "bt"}:
+        n_layers, n_pages = caches["k"].shape[:2]
+        arena = {lk: caches[lk].reshape((n_layers * n_pages,)
+                                        + caches[lk].shape[2:])
+                 for lk in ("k", "v")}
+
     def body(carry, xs):
-        xc, aux_sum = carry
+        xc, aux_sum, arena_c = carry
         if caches is None:
             bp = xs
             xc, aux, _ = _block(xc, bp, cfg, positions, moe=moe,
                                 q_offset=q_offset, window=cfg.window)
-            return (xc, aux_sum + aux), None
-        bp, cache_l = xs
+            return (xc, aux_sum + aux, arena_c), None
+        if arena_c is None:
+            bp, cache_l = xs
+        else:
+            bp, (layer, bt) = xs
+            cache_l = {**arena_c, "bt": _layer_block_table(
+                bt, layer, n_pages, n_layers)}
         xc, aux, nc = _block(xc, bp, cfg, positions, moe=moe, cache=cache_l,
                              q_offset=q_offset, window=cfg.window,
                              slot_positions=slot_positions,
                              slot_done=slot_done, plens=plens,
                              chunk_offsets=chunk_offsets)
-        return (xc, aux_sum + aux), nc
+        if arena_c is None:
+            return (xc, aux_sum + aux, None), nc
+        return (xc, aux_sum + aux, {lk: nc[lk] for lk in arena_c}), None
 
     if cfg.remat == "block":
         body = jax.remat(body, prevent_cse=False)
@@ -760,10 +795,19 @@ def _run_group(x, group, cfg, positions, *, moe, caches=None, q_offset=0,
         body = jax.remat(
             body, prevent_cse=False,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    xs = group if caches is None else (group, caches)
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs,
+    if caches is None:
+        xs = group
+    elif arena is not None:
+        xs = (group, (jnp.arange(n_layers, dtype=caches["bt"].dtype),
+                      caches["bt"]))
+    else:
+        xs = (group, caches)
+    (x, aux, arena), new_caches = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), arena), xs,
         unroll=cfg.unroll_scans)
+    if arena is not None:
+        new_caches = {**caches, **{lk: a.reshape(caches[lk].shape)
+                                   for lk, a in arena.items()}}
     return x, aux, new_caches
 
 

@@ -17,8 +17,11 @@ with ``page`` the ``pad_cache_len`` quantum for ``S`` (8 below 256, 64
 above).  The block table rides inside the group dict, tiled identically
 per layer, so it flows through ``lax.scan`` over the layer axis with
 zero plumbing changes; model code detects a paged group purely by
-``"bt" in cache``.  Leaves of a declared group that are NOT named
-(xlstm's mLSTM C/n/m carries) stay dense-per-slot inside the same dict.
+``"bt" in cache``.  (The transformer's slot decode scans only the table
+and carries its k/v arenas whole, offsetting each layer's table into
+them: ``models.transformer._run_group``.)  Leaves of a declared group
+that are NOT named (xlstm's mLSTM C/n/m carries) stay dense-per-slot
+inside the same dict.
 
 Page-id conventions
 -------------------

@@ -11,16 +11,27 @@ topology, at published widths:
 
 Nothing runs, so this says nothing about results or speed; the kernels'
 numerics are covered in interpret mode by ``test_kernels.py``.
+
+The qwen1.5-0.5b paged slot-decode loop is compiled whole as well, and its
+compiled text is checked for data movement of arena size: the paged K/V
+arenas must be updated in place through the decode loop, not copied or
+sliced out per layer and step.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import get_config
 from repro.kernels import decode_attention as da
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
+from repro.models import get_family
+from repro.serve.paged import build_paged_pool
+from repro.train.steps import make_slot_decode_loop
 
 # (heads, kv heads, head dim, dtype) of each width under test
 WIDTHS = {
@@ -96,3 +107,80 @@ KERNELS = {
 def test_kernel_compiles_for_v5e(kernel, width, one_chip):
     compiled = KERNELS[kernel](_args(width, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# one HLO instruction: name, result type, opcode, first operand
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\("
+                    r"%?([\w.\-]*)")
+_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+          "dynamic-update-slice")
+
+
+def _arena_moves(text, arena_shapes):
+    """(in_entry, name, opcode, result type, first operand) of every
+    data-movement instruction in compiled HLO ``text`` whose result has
+    one of ``arena_shapes``, and the result type of every instruction of
+    the entry computation by name."""
+    moves, layouts, entry = [], {}, False
+    for line in text.splitlines():
+        if line.startswith("ENTRY"):
+            entry = True
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, rtype, op, operand = m.groups()
+        if entry:
+            layouts[name] = rtype
+        if op not in _MOVES:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]*)\]", rtype):
+            if tuple(int(d) for d in dims.split(",") if d) in arena_shapes:
+                moves.append((entry, name, op, rtype, operand))
+    return moves, layouts
+
+
+def test_paged_decode_loop_keeps_arenas_in_place(one_chip, monkeypatch):
+    """The qwen1.5-0.5b paged slot-decode loop (published widths, page
+    64, capacity 8, max_len 2560, k 2) moves no arena inside its loops:
+    no copy, dynamic slice or dynamic update of a whole arena or of one
+    layer's arena per step.  All that is left is one layout conversion of
+    each arena on entry and one on exit: the pool's default device layout
+    puts the page axis minor (hd 64 is narrower than a lane tile), and
+    the kernel reads pages row-major."""
+    # ``auto`` checks the process's default backend, which is not the
+    # described chip: take the compiled-kernel branch directly
+    monkeypatch.setattr(ops, "_interp", lambda mode: mode == "interpret")
+    cfg = get_config("qwen1.5-0.5b").replace(decode_kernel="auto")
+    fam = get_family(cfg)
+
+    def on_chip(shapes):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                            shapes)
+
+    params = on_chip(jax.eval_shape(
+        lambda: fam.init(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(
+        lambda: build_paged_pool(fam, cfg, B, 2560)[0]))
+    rows = _sds((B,), jnp.int32, one_chip)
+    done = _sds((B,), jnp.bool_, one_chip)
+    # the engine's donation: state and pool in, state and pool out
+    loop = jax.jit(make_slot_decode_loop(cfg, 2),
+                   donate_argnums=(1, 2, 3, 5, 6))
+    text = loop.lower(params, rows, rows, rows, rows, done,
+                      pool).compile().as_text()
+    assert text.startswith("HloModule jit_loop_fn")
+    assert "tpu_custom_call" in text
+    L, n_pages = pool["dense"]["k"].shape[:2]
+    tail = pool["dense"]["k"].shape[2:]
+    moves, layouts = _arena_moves(text, {(L, n_pages) + tail,
+                                         (n_pages,) + tail,
+                                         (L * n_pages,) + tail})
+    per_step = [m for m in moves if not m[0]]
+    assert not per_step, "\n".join(f"{m[2]} {m[1]} {m[3]}"
+                                    for m in per_step)
+    # entry and exit: K and V each converted once, layout to layout
+    assert len(moves) <= 4, moves
+    for _, name, op, rtype, operand in moves:
+        layout = re.search(r"\{[^}]*\}", rtype).group(0)
+        assert op == "copy" and layout not in layouts[operand], (
+            name, rtype, layouts[operand])

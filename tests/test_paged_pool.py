@@ -783,3 +783,67 @@ def test_oversize_rejection_is_resubmittable(qwen_smoke_cfg,
     # resubmit the same uid with a servable budget: accepted this time
     got = engine.run(_requests(cfg, [(4, 3)], seed0=500))
     assert set(got) == {0} and len(got[0]) == 3
+
+
+@pytest.mark.parametrize("kernel", ["jnp", "interpret"])
+@pytest.mark.parametrize("window", [None, 16])
+def test_paged_slot_loop_layer_offsets(gpt_micro_cfg, window, kernel):
+    """The decode loop carries each paged arena whole through the layer
+    scan and reaches layer ``l``'s pages through an offset block table.
+    Over a pool of random bytes with one live row, one done row and one
+    live row whose table holds sentinel entries where it writes: every
+    page no live row owns keeps its bytes in every layer (a sentinel or a
+    done row's write shifted onto another layer would show), the block
+    table comes back unchanged, and the fully-paged live row decodes the
+    same tokens as the dense pool holding the same bytes."""
+    from repro.models import attention as attn_lib
+    from repro.models import get_family
+    from repro.serve.paged import build_paged_pool
+    from repro.train.steps import make_slot_decode_loop
+
+    cfg = gpt_micro_cfg.replace(decode_kernel=kernel, window=window)
+    fam = get_family(cfg)
+    params = fam.init(jax.random.PRNGKey(0), cfg)
+    pool, meta = build_paged_pool(fam, cfg, 3, MAX_LEN)
+    n, nblk, S = meta.n_pages, meta.nblk, meta.nblk * meta.page
+    grp = pool["dense"]
+    keys = jax.random.split(jax.random.PRNGKey(1), 2)
+    for key, lk in zip(keys, ("k", "v")):
+        grp[lk] = jax.random.normal(key, grp[lk].shape, grp[lk].dtype)
+    sent = meta.sentinel
+    if window is None:  # page 8, 4 blocks: positions 9.., 10, 7..
+        bt = [[5, 6, 7, 8], [1, 2, sent, sent], [3, sent, sent, sent]]
+        positions, live_pages, partial = [9, 10, 7], {5, 6, 7, 8, 3}, 3
+    else:  # a 16-deep ring of 2 pages: row 0 has wrapped
+        bt = [[2, 3], [4, 5], [1, sent]]
+        positions, live_pages, partial = [20, 10, 5], {2, 3, 1}, 1
+    assert len(bt[0]) == nblk
+    grp["bt"] = jnp.broadcast_to(jnp.asarray(bt, jnp.int32),
+                                 grp["bt"].shape)
+    free = sorted(set(range(n)) - live_pages)
+    assert 0 in free  # where a shifted sentinel write would land
+    before = jax.tree.map(np.asarray, grp)
+
+    def run(cache):
+        loop = jax.jit(make_slot_decode_loop(cfg, 4))
+        tokens = jnp.asarray([11, 12, 13], jnp.int32)
+        out = loop(params, tokens, jnp.asarray(positions, jnp.int32),
+                   jnp.full((3,), 100, jnp.int32),
+                   jnp.full((3,), -1, jnp.int32),
+                   jnp.asarray([False, True, False]), cache)
+        return np.asarray(out[0]), out[-1]
+
+    dense = {"dense": {lk: jax.vmap(attn_lib.paged_gather)(grp[lk], grp["bt"])
+                       for lk in ("k", "v")}}
+    assert dense["dense"]["k"].shape[2] == S
+    got, new = run(pool)
+    want, _ = run(dense)
+    np.testing.assert_array_equal(got[:, 0], want[:, 0])
+    after = jax.tree.map(np.asarray, new["dense"])
+    np.testing.assert_array_equal(after["bt"], before["bt"])
+    for lk in ("k", "v"):
+        np.testing.assert_array_equal(after[lk][:, free],
+                                      before[lk][:, free], err_msg=lk)
+        # the row with sentinel entries wrote its one real page
+        assert not np.array_equal(after[lk][:, partial],
+                                  before[lk][:, partial])
