@@ -2,9 +2,10 @@
 
 Once the window has closed, a sample of the finished requests, drawn
 from the seed and always holding the longest, is run through the plain
-reference over its prompt and served tokens.  For each served token the
-gap is how far its reference logit lies below the reference's best at
-that position.  Greedy decoding of an exact program gives 0; rounding
+reference over its prompt and served tokens (the configuration's own
+reference module, ``bench.common.load_reference``).  For each served
+token the gap is how far its reference logit lies below the reference's
+best at that position.  Greedy decoding of an exact program gives 0; rounding
 flips near-ties.  A cell's file names the numbers it compares and their
 limits; the others are reported beside them.  The widest gap did not
 separate the control from the program by the factor of three a limit
@@ -20,8 +21,6 @@ and reads that token's gap in the float32 reference.
 from __future__ import annotations
 
 import numpy as np
-
-from bench.reference import transformer as ref
 
 # a sample holds the longest request, then random finished ones until it
 # holds this many served tokens (or MAX_REQUESTS requests)
@@ -50,11 +49,12 @@ def sample(trails, seed):
     return picked
 
 
-def token_gaps(spec, params, prompt, tokens, first, last, control=None):
+def token_gaps(ref, spec, params, prompt, tokens, first, last,
+               control=None):
     """Gaps of ``tokens[first:last]`` (served after ``prompt`` and the
-    tokens before them).  ``control=(precision, params)`` reads the
-    tokens that the reference computed in ``precision`` ranks first
-    instead of the served ones."""
+    tokens before them), by the reference module ``ref``.
+    ``control=(precision, params)`` reads the tokens that the reference
+    computed in ``precision`` ranks first instead of the served ones."""
     seq = np.concatenate([prompt, np.asarray(tokens[:-1], np.int32)])
     pos = np.arange(len(prompt) - 1 + first, len(prompt) - 1 + last)
     lg = np.asarray(ref.logits_at(spec, params, seq, pos))

@@ -28,7 +28,6 @@ import numpy as np
 from bench import check, common, weights
 from bench import trace as trace_lib
 from bench.recorder import CompileClock, Recorder
-from bench.reference import transformer as ref
 from bench.traffic import Traffic
 
 PEAKS = common.BENCH_DIR / "peaks.json"
@@ -59,6 +58,7 @@ class Run:
     setup_compile_s: float
     counters: dict
     model: dict  # the plain description of the model served
+    reference: object  # the configuration's reference module
     value_bytes: int  # bytes of an activation and a cached K/V value
     peak: dict
     trace: Optional[trace_lib.Trace] = None
@@ -83,12 +83,11 @@ def _program():
                 Engine=ContinuousBatchingEngine, Request=Request)
 
 
-def _same_widths(cfg, spec):
-    got = dict(n_layers=cfg.n_layers, d_model=cfg.d_model,
-               n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-               head_dim=cfg.head_dim, d_ff=cfg.d_ff, vocab=cfg.vocab_size,
-               dtype=cfg.param_dtype)
-    bad = {k: (v, spec[k]) for k, v in got.items() if v != spec[k]}
+def _same_widths(cfg, widths):
+    """Raise unless the program's configuration holds ``widths`` (the
+    reference's ``program_widths``): {field: (program's, file's)}."""
+    bad = {k: (getattr(cfg, k), v) for k, v in widths.items()
+           if getattr(cfg, k) != v}
     if bad:
         raise ValueError(f"the program's model is not the configuration "
                          f"file's: {bad}")
@@ -103,8 +102,9 @@ class Session:
         prog = cell.config["program"]
         self.cfg = P["get_config"](prog["arch"]).replace(
             decode_kernel=prog["decode_kernel"])
-        self.spec = ref.describe(cell.config)
-        _same_widths(self.cfg, self.spec)
+        self.ref = cell.reference
+        self.spec = self.ref.describe(cell.config)
+        _same_widths(self.cfg, self.ref.program_widths(self.spec))
         g = cell.serve
         self.geom = dict(capacity=g["capacity"], max_len=g["max_len"],
                          prefill_bucket=g["prefill_bucket"], k=g["k"],
@@ -251,11 +251,14 @@ def run_window(sess, seconds, traced, root):
     """The measured window.  Returns (Run fields, trace or None)."""
     rec = Recorder()
     eng = sess.engine(journal=rec)
+    programs = None
     if traced:
         for attr, label in (("_admit_batch", "bench.admit"),
                             ("_dispatch", "bench.dispatch"),
                             ("_process", "bench.readback")):
             _annotate(eng, attr, label)
+        programs = trace_lib.Programs()
+        programs.watch(eng)
     load = Load(sess, eng, rec, seconds)
     found = None
     gc_spans = []  # (start, end) of each garbage collection
@@ -291,6 +294,7 @@ def run_window(sess, seconds, traced, root):
     # no token reached it, and the seconds the garbage collector ran
     times = sorted(t for t in rec.token_times() if t0 <= t <= t0 + seconds)
     out = dict(rec=rec, t0=t0, t_end=t_end, counters=counters,
+               programs=programs,
                window_programs=clock.programs,
                window_compile_s=clock.seconds,
                longest_token_gap_s=max(
@@ -298,6 +302,27 @@ def run_window(sess, seconds, traced, root):
                    default=None),
                gc_s=sum(e - s for s, e in gc_spans if e is not None))
     return out, xplane
+
+
+def scoped_programs(root, metrics):
+    """The programs whose ops the readers of ``metrics`` read by scope:
+    the union of their ``SCOPE_MAP``s."""
+    return set().union(*(getattr(common.load_reader(root, m["name"]),
+                                 "SCOPE_MAP", ()) for m in metrics))
+
+
+def place_ops(trace, programs, wanted, lo, hi):
+    """Fill the trace's scope map from the compiled text of each program
+    in ``wanted`` that the window ran; returns what it took and the ops
+    of those programs that it could not place."""
+    t = time.monotonic()
+    n = 0
+    for text in programs.texts(trace.program_names() & set(wanted)):
+        trace.add_program_text(text)
+        n += 1
+    return {"scope_map_s": time.monotonic() - t, "scope_map_programs": n,
+            "unplaced_ops": {p: v for p, v in trace.unplaced_ops(lo, hi)
+                             .items() if p in wanted}}
 
 
 # -------------------------------------------------------------- metrics
@@ -320,7 +345,7 @@ def compare(sess, rec, limits, control=False):
     ok = all(t.outcome == "finished" for t in rec.measured())
     # the sample may hold requests finished past the window too
     picked = check.sample(rec.trails.values(), sess.seed)
-    gs = [check.token_gaps(sess.spec, sess.params,
+    gs = [check.token_gaps(sess.ref, sess.spec, sess.params,
                            sess.traffic.tokens(t.uid, t.prompt_len),
                            t.tokens, 0, len(t.tokens), ctl)
           for t in picked]
@@ -377,7 +402,7 @@ def run_cell(root, workload, seed, seconds, traced, *, require_chip=True,
     run = Run(cell=cell, seconds=seconds, rec=w["rec"], t0=w["t0"],
               t_end=w["t_end"], setup_s=setup_s,
               setup_compile_s=setup_clock.seconds, counters=w["counters"],
-              model=sess.spec,
+              model=sess.spec, reference=sess.ref,
               value_bytes=np.dtype(sess.cfg.compute_dtype).itemsize,
               peak=peak)
     print(json.dumps({"programs_compiled_in_window": w["window_programs"],
@@ -391,9 +416,15 @@ def run_cell(root, workload, seed, seconds, traced, *, require_chip=True,
                             for t in run.rec.measured())}
     breakdown = None
     if traced:
+        t = time.monotonic()
         run.trace = trace_lib.load(xplane)
         shutil.rmtree(pathlib.Path(root) / TRACE_DIR, ignore_errors=True)
         run.tlo, run.thi = run.trace.window()
+        print(json.dumps({"trace_load_s": time.monotonic() - t,
+                          **place_ops(run.trace, w["programs"],
+                                      scoped_programs(root, cell.per_layer),
+                                      run.tlo, run.thi)}),
+              file=sys.stderr, flush=True)
         device["busy_s"] = run.trace.busy_ns(run.tlo, run.thi) / 1e9
         device["window_s"] = run.trace_window_s
         breakdown = {"device_ops": run.trace.top_ops(run.tlo, run.thi),
@@ -401,7 +432,10 @@ def run_cell(root, workload, seed, seconds, traced, *, require_chip=True,
         metrics = read_metrics(root, cell.per_layer, run)
     else:
         metrics = read_metrics(root, cell.end_to_end, run)
+    t = time.monotonic()
     ok, numbers = compare(sess, run.rec, cell.serve["limits"])
+    print(json.dumps({"check_s": time.monotonic() - t}), file=sys.stderr,
+          flush=True)
     result["correct"] = bool(ok)
     result["metrics"] = metrics
     result["device"] = device

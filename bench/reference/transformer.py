@@ -20,6 +20,9 @@ Features of the Qwen2 family, as the configuration file states them:
 (matmul inputs scaled per tensor into float8_e4m3, products in float32)
 — the latter two are the controls one precision step below a float32
 and a bfloat16 configuration.
+
+The module keeps the interface of ``bench.common.load_reference``; its
+useful work is that of ``bench/flops.py``.
 """
 from __future__ import annotations
 
@@ -29,6 +32,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import flops
 
 QBLOCK = 512  # query block of the attention
 SEQ_QUANTUM = 512  # sequences are padded to a multiple (fewer compiles)
@@ -48,6 +53,22 @@ def describe(c):
         norm_eps=c["rms_norm_eps"], tied=c["tie_word_embeddings"],
         rope_theta=c["rope_theta"], max_pos=c["max_position_embeddings"],
         dtype=c["torch_dtype"])
+
+
+def program_widths(spec):
+    """The widths the program's configuration must hold."""
+    return dict(n_layers=spec["n_layers"], d_model=spec["d_model"],
+                n_heads=spec["n_heads"], n_kv_heads=spec["n_kv_heads"],
+                head_dim=spec["head_dim"], d_ff=spec["d_ff"],
+                vocab_size=spec["vocab"], param_dtype=spec["dtype"])
+
+
+def prefill_flops(spec, n):
+    return flops.prefill_flops(spec, n)
+
+
+def decode_flops(spec, kv_len):
+    return flops.decode_flops(spec, kv_len)
 
 
 # ------------------------------------------------------------- arithmetic

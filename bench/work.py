@@ -3,10 +3,10 @@ device time or peak by.
 
 A request's first token comes from its admission prefill; output token
 ``j >= 1`` comes from a decode step that attends ``prompt + j`` keys.
+The operations of each come from the configuration's reference module,
+so every model family counts its own work.
 """
 from __future__ import annotations
-
-from bench import flops
 
 
 def prefill_prompts(run):
@@ -28,7 +28,8 @@ def decode_tokens(run):
 
 def useful_flops(run):
     """Prompt and delivered tokens through the model."""
-    return (sum(flops.prefill_flops(run.model, n)
+    ref = run.reference
+    return (sum(ref.prefill_flops(run.model, n)
                 for n in prefill_prompts(run))
-            + sum(flops.decode_flops(run.model, keys)
+            + sum(ref.decode_flops(run.model, keys)
                   for keys in decode_tokens(run)))

@@ -1,7 +1,8 @@
 """A copy of the benchmark's data files at CPU-test sizes.
 
 ``make_root(dst)`` writes ``BENCHMARK.json`` and ``bench/`` data (configs,
-traffic, cells; the metric readers are copied as they are) into ``dst``,
+traffic, cells; the metric readers and the reference modules are copied
+as they are) into ``dst``,
 with each configuration swapped for the repository's smoke-size twin and
 the Pallas kernels for the jnp path, so the harness can drive a whole
 run on the CPU.
@@ -20,6 +21,7 @@ QWEN = {
     "rope_theta": 1000000.0, "tie_word_embeddings": True,
     "hidden_act": "silu", "torch_dtype": "float32",
     "program": {"arch": "qwen1.5-0.5b-smoke", "decode_kernel": "jnp"},
+    "reference": "bench/reference/transformer.py",
 }
 
 
@@ -65,8 +67,10 @@ def make_root(dst):
     (dst / "bench" / "configs").mkdir(parents=True, exist_ok=True)
     (dst / "bench" / "traffic").mkdir(parents=True, exist_ok=True)
     (dst / "bench" / "cells").mkdir(parents=True, exist_ok=True)
-    shutil.copytree(REPO / "bench" / "metrics", dst / "bench" / "metrics",
-                    dirs_exist_ok=True)
+    for sub in ("metrics", "reference"):
+        shutil.copytree(REPO / "bench" / sub, dst / "bench" / sub,
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for c in man["configs"]:
         (dst / c["file"]).write_text(json.dumps(QWEN))
     for name, body in TRAFFIC.items():

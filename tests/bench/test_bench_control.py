@@ -46,9 +46,10 @@ def test_control_fails_the_limit_that_exact_serving_meets(
         prompt = np.random.default_rng(seed).integers(
             0, cfg.vocab_size, 16, dtype=np.int32)
         toks = _greedy(spec, params, prompt, 120)
-        served.append(check.token_gaps(spec, params, prompt, toks, 0, 120))
-        control.append(check.token_gaps(spec, params, prompt, toks, 0, 120,
-                                        (lower, params)))
+        served.append(check.token_gaps(ref, spec, params, prompt, toks, 0,
+                                       120))
+        control.append(check.token_gaps(ref, spec, params, prompt, toks, 0,
+                                        120, (lower, params)))
     assert check.mean(served) <= LIMIT
     assert check.mean(control) > LIMIT
 
