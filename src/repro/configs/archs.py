@@ -39,6 +39,11 @@ def phi35_moe_smoke():
 
 @register_named("deepseek-v3-671b")
 def deepseek_v3():
+    """DeepSeek-V3 as published (HF deepseek-ai/DeepSeek-V3 config.json):
+    MLA with YaRN (factor 40 over 4096 positions), 3 dense layers then 58
+    expert layers routing 8 of 256 experts by the noaux_tc gate (sigmoid
+    scores, correction bias, 4 of 8 groups, routed scale 2.5) plus one
+    shared expert; one MTP module."""
     return ModelConfig(
         name="deepseek-v3-671b", family="transformer",
         n_layers=61, d_model=7168, n_heads=128, n_kv_heads=128,
@@ -47,20 +52,58 @@ def deepseek_v3():
         qk_rope_dim=64, v_head_dim=128,
         moe=True, moe_layer_start=3, n_experts=256, top_k=8,
         n_shared_experts=1, expert_d_ff=2048, router_score="sigmoid",
+        n_group=8, topk_group=4, router_bias=True, routed_scale=2.5,
         capacity_factor=1.25, aux_loss_weight=1e-4,
+        yarn_factor=40.0, yarn_original_max_pos=4096, yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0, yarn_mscale=1.0, yarn_mscale_all_dim=1.0,
         mtp=True, act="swiglu", norm="rms", rope_theta=10000.0,
-        max_seq_len=131072, **_SCALE)
+        max_seq_len=163840, **_SCALE)
+
+
+_DSV3_SMOKE = dict(
+    d_model=64, n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128,
+    vocab_size=128, n_experts=8, top_k=2, n_group=4, topk_group=2,
+    expert_d_ff=64, q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
+    qk_rope_dim=8, v_head_dim=16, max_seq_len=256, param_dtype="float32",
+    compute_dtype="float32", attn_chunk=16)
 
 
 @register_named("deepseek-v3-671b-smoke")
 def deepseek_v3_smoke():
     return deepseek_v3().replace(
-        name="deepseek-v3-671b-smoke", n_layers=3, d_model=64, n_heads=4,
-        n_kv_heads=4, head_dim=16, d_ff=128, vocab_size=128,
-        moe_layer_start=1, n_experts=4, top_k=2, expert_d_ff=64,
-        q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8,
-        v_head_dim=16, max_seq_len=256, param_dtype="float32",
-        compute_dtype="float32", attn_chunk=16)
+        name="deepseek-v3-671b-smoke", n_layers=3, moe_layer_start=1,
+        **_DSV3_SMOKE)
+
+
+@register_named("deepseek-v3-ep32")
+def deepseek_v3_ep32():
+    """One chip's share of DeepSeek-V3 served expert-parallel over 32
+    chips (DeepSeek's own EP32 prefill deployment, open-infra-index):
+    this chip holds routed experts 0-7 of every expert layer, and MLA,
+    the shared expert, the embedding and the head whole.  Depth is cut to
+    the first dense layer and 4 expert layers (the rest would lie on
+    further pipeline stages); the MTP draft is not on the serving path.
+    Every width, the 256-wide router and its top-8 are as published.
+    The correction bias is served at zero, as a trained one balances a
+    router whose load is balanced to begin with (a random one's); a
+    random draw would make some experts several times as popular as
+    others.  Prefill attention runs 128 queries at a time, so that the
+    32 x 512 admission group's float32 scores (32 x 128 heads x 128 x
+    512) fit beside the 9.56 GB of weights."""
+    return deepseek_v3().replace(
+        name="deepseek-v3-ep32", n_layers=5, moe_layer_start=1,
+        experts_held=8, experts_offset=0, router_bias=False, mtp=False,
+        attn_chunk=128)
+
+
+@register_named("deepseek-v3-ep32-smoke")
+def deepseek_v3_ep32_smoke():
+    """CPU twin of deepseek-v3-ep32: holds 2 of the 8 experts it routes
+    over, YaRN and the noaux_tc gate (correction bias at zero) as
+    deepseek-v3-ep32 has them."""
+    return deepseek_v3_ep32().replace(
+        name="deepseek-v3-ep32-smoke", n_layers=3, experts_held=2,
+        **_DSV3_SMOKE)
 
 
 @register_named("stablelm-3b")

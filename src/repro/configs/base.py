@@ -56,6 +56,25 @@ class ModelConfig:
     moe_dispatch_dtype: str = "float32"  # bf16: halves dispatch bytes
     moe_layer_start: int = 0
     aux_loss_weight: float = 0.01
+    # expert parallelism: this chip holds experts [experts_offset,
+    # experts_offset + experts_held) of the n_experts the router chooses
+    # among (0: all of them)
+    experts_held: int = 0
+    experts_offset: int = 0
+    # group-limited routing (DeepSeek-V3 noaux_tc): experts form n_group
+    # groups, a token keeps its topk_group best groups
+    n_group: int = 1
+    topk_group: int = 1
+    router_bias: bool = False  # correction bias added for selection only
+    routed_scale: float = 1.0  # routed weights' scale after renormalizing
+
+    # --- YaRN rope scaling (factor > 1 turns it on) ---
+    yarn_factor: float = 1.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- MLA (DeepSeek) ---
     mla: bool = False
@@ -112,6 +131,10 @@ class ModelConfig:
     @property
     def n_dense_layers(self):
         return self.moe_layer_start if self.moe else self.n_layers
+
+    @property
+    def n_experts_held(self):
+        return self.experts_held or self.n_experts
 
     def replace(self, **kw):
         return dataclasses.replace(self, **kw)

@@ -9,9 +9,10 @@ Every family module exposes the same functional interface:
 Families that serve through the continuous-batching engine additionally
 implement the SLOT-STATE PROTOCOL (see docs/serving.md):
   cache_specs(cfg)                 -> logical axes of the slot pool
-  prefill_full(params, batch, cfg, cache)
+  prefill_last(params, batch, cfg, cache)
       batch = {"tokens": (B, S) bucket-padded, "plens": (B,) true lengths}
-      -> (logits (B, S, V), cache after each row's REAL prompt)
+      -> (logits (B, V) at each row's last real prompt position,
+          cache after each row's REAL prompt)
   decode_step_slots(params, tokens, positions, cache, cfg, done=None)
       one token per slot at per-slot lengths; ``done`` rows are exact
       no-ops (frozen state / bit-identical cache re-stores)
@@ -69,7 +70,7 @@ def serve_supported(cfg):
     """
     fam = get_family(cfg)
     probe = getattr(fam, "serve_supported", None)
-    if probe is None or not (hasattr(fam, "prefill_full")
+    if probe is None or not (hasattr(fam, "prefill_last")
                              and hasattr(fam, "decode_step_slots")):
         return False, (f"family {cfg.family!r} does not implement the "
                        "slot-state protocol")

@@ -428,9 +428,13 @@ def _forward_cached(params, batch, cfg, cache, q_offset, plens=None):
     positions = jnp.broadcast_to(positions, (B, S))
     x, new_cache = _run_blocks(params, x, cfg, positions, caches=cache,
                                q_offset=q_offset, plens=plens)
+    if plens is not None:
+        # admission: the head only at each row's last real position
+        x = x[jnp.arange(B), plens - 1][:, None]
     x = apply_norm(x, params["final_norm"], cfg.norm)
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    return jnp.einsum("bsd,dv->bsv", x, w.astype(cdt)), new_cache
+    logits = jnp.einsum("bsd,dv->bsv", x, w.astype(cdt))
+    return (logits[:, 0] if plens is not None else logits), new_cache
 
 
 def prefill(params, batch, cfg, cache):
@@ -444,8 +448,9 @@ def decode_step(params, tokens, pos, cache, cfg):
     return logits[:, -1], cache
 
 
-def prefill_full(params, batch, cfg, cache):
-    """Admission prefill: logits at EVERY position + per-row final state.
+def prefill_last(params, batch, cfg, cache):
+    """Admission prefill: logits (B, V) at each row's last real prompt
+    position + per-row final state.
 
     ``batch["plens"]`` (B,) carries each row's true prompt length: RG-LRU
     pad positions freeze to identity, conv tails are gathered at the row
@@ -453,8 +458,8 @@ def prefill_full(params, batch, cfg, cache):
     position — so the returned cache is exactly the state after each
     row's REAL prompt, tail padding notwithstanding.
     """
-    plens = batch.get("plens")
-    batch = {k: v for k, v in batch.items() if k != "plens"}
+    batch = dict(batch)
+    plens = batch.pop("plens")
     return _forward_cached(params, batch, cfg, cache, 0, plens=plens)
 
 

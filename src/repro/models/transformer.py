@@ -3,8 +3,10 @@
 One functional implementation covers:
   * dense decoder LMs       (qwen1.5, qwen3, stablelm, yi, GPT)
   * encoder-only            (hubert, BERT)          — ``causal=False``
-  * MoE decoders            (phi3.5-moe)            — GSPMD capacity dispatch
-  * MLA + MoE + MTP         (deepseek-v3)           — latent attention
+  * MoE decoders            (phi3.5-moe)            — dropless experts held
+                                                      (serving), capacity
+                                                      dispatch (training)
+  * MLA + MoE + MTP         (deepseek-v3)           — latent attention, YaRN
   * VLM backbones           (qwen2-vl)              — M-RoPE, stub frontend
 
 Parameters are plain dicts; per-layer weights are stacked on a leading L axis
@@ -33,7 +35,7 @@ from repro.models.common import (
     rms_norm,
     trunc_normal,
 )
-from repro.models.rope import apply_mrope, apply_rope
+from repro.models.rope import apply_mrope, apply_rope, cfg_rope, yarn_mscale
 from repro.serve import tracing
 
 
@@ -516,12 +518,13 @@ def _mla_forward(x, p, cfg, positions, *, cache=None, q_offset=0, kv_len=None,
         q = jnp.einsum("bsr,rh->bsh", cq, p["w_uq"].astype(cdt))
         q = q.reshape(B, S, H, dn + dr)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
-        q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta)
+        freqs, mscale = cfg_rope(cfg, dr)
+        q_rope = apply_rope(q_rope, positions, freqs=freqs, mscale=mscale)
 
         ckv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(cdt))
         kr = jnp.einsum("bsd,dr->bsr", x, p["w_kr"].astype(cdt))
-        kr = apply_rope(kr[:, :, None, :], positions,
-                        theta=cfg.rope_theta)[:, :, 0]
+        kr = apply_rope(kr[:, :, None, :], positions, freqs=freqs,
+                        mscale=mscale)[:, :, 0]
 
     if chunk_offsets is not None:
         # speculative verify in the latent space: absorbed-weight
@@ -595,6 +598,11 @@ def _mla_forward(x, p, cfg, positions, *, cache=None, q_offset=0, kv_len=None,
                     q_nope, q_rope, cc.astype(cdt), cr.astype(cdt), p, cfg,
                     kv_len=q_offset + 1)
             return _mla_out(out, p, cdt), new_cache
+        if isinstance(q_offset, int) and q_offset == 0:
+            # a prompt from offset 0 attends its own S positions only
+            # (out of the cache, so cache-dtype rounding is kept), not
+            # the whole max_len cache behind the kv_len mask
+            cc, cr = cc[:, :S], cr[:, :S]
         ckv, kr = cc.astype(cdt), cr.astype(cdt)
         kv_len = q_offset + S
 
@@ -614,12 +622,19 @@ def _mla_forward(x, p, cfg, positions, *, cache=None, q_offset=0, kv_len=None,
         v = annotate(v, ("batch", "seq", "heads", "head_dim"))
         out = attn_lib.attention(
             qf, kf, v, causal=cfg.causal, q_offset=q_offset, kv_len=kv_len,
-            scale=(dn + dr) ** -0.5, chunk_q=cfg.attn_chunk,
+            scale=_mla_scale(cfg), chunk_q=cfg.attn_chunk,
             unroll=cfg.unroll_scans,
             logits_dtype=jnp.dtype(cfg.attn_logits_dtype),
             prefix_chunks=cfg.attn_prefix_chunks)
         out = out.reshape(B, S, H * dv)
     return _mla_out(out, p, cdt), new_cache
+
+
+def _mla_scale(cfg):
+    """MLA's softmax scale: 1/sqrt(q head width), times YaRN's mscale
+    squared where the config scales its rope."""
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 * m * m
 
 
 def _mla_out(out, p, cdt):
@@ -658,7 +673,7 @@ def _mla_absorbed_decode(q_nope, q_rope, ckv, kr, p, cfg, *, kv_len):
                         preferred_element_type=jnp.float32)
     logits += jnp.einsum("bqhd,bsd->bhqs", q_rope, kr,
                          preferred_element_type=jnp.float32)
-    logits *= (dn + cfg.qk_rope_dim) ** -0.5
+    logits *= _mla_scale(cfg)
     kvl = jnp.asarray(kv_len)
     if kvl.ndim == 0:
         mask = (jnp.arange(ckv.shape[1]) < kvl)[None, None, None]
@@ -696,7 +711,7 @@ def _mla_chunk_verify(q_nope, q_rope, cache, ckv, kr, p, cfg, offsets, done):
                         preferred_element_type=jnp.float32)
     logits += jnp.einsum("bqhd,bsd->bhqs", q_rope, kr_all,
                          preferred_element_type=jnp.float32)
-    logits *= (dn + cfg.qk_rope_dim) ** -0.5
+    logits *= _mla_scale(cfg)
     kpos = attn_lib.chunk_verify_kpos(offsets, cache["ckv"].shape[1], S,
                                       ring=False)
     mask = attn_lib.chunk_verify_mask(offsets, kpos, S, done=done)
@@ -712,7 +727,13 @@ def _mla_chunk_verify(q_nope, q_rope, cache, ckv, kr, p, cfg, offsets, done):
 
 def _block(x, bp, cfg, positions, *, moe, cache=None, q_offset=0,
            window=None, slot_positions=None, slot_done=None, plens=None,
-           chunk_offsets=None):
+           chunk_offsets=None, live=None):
+    """One block.  Returns (x, aux loss, MoE stats (2,) int32, cache).
+
+    Expert blocks run the dropless layer over the experts held on every
+    serving path (a cache is given); ``live`` (B, S) marks the rows whose
+    routed pairs it computes.  The training forward keeps capacity
+    dispatch."""
     with tracing.scope("attn.qkv"):
         xn = apply_norm(x, bp["ln1"], cfg.norm)
     h, new_cache = _attn_forward(
@@ -724,13 +745,16 @@ def _block(x, bp, cfg, positions, *, moe, cache=None, q_offset=0,
         x = x + h
     with tracing.scope("mlp"):
         hin = apply_norm(x, bp["ln2"], cfg.norm)
-        if moe:
+        aux, stats = 0.0, jnp.zeros((2,), jnp.int32)
+        if moe and cache is not None:
+            h, stats = moe_lib.dropless_moe(hin, bp["moe"], cfg, live=live)
+        elif moe:
             h, aux = moe_lib.moe_mlp(hin, bp["moe"], cfg)
         else:
-            h, aux = ffn_lib.mlp(hin, bp["mlp"], cfg.act), 0.0
+            h = ffn_lib.mlp(hin, bp["mlp"], cfg.act)
         x = x + h
         x = annotate(x, ("batch", "seq", "embed"))
-    return x, aux, new_cache
+    return x, aux, stats, new_cache
 
 
 def _layer_block_table(bt, layer, n_pages, n_layers):
@@ -746,46 +770,62 @@ def _layer_block_table(bt, layer, n_pages, n_layers):
 
 def _run_group(x, group, cfg, positions, *, moe, caches=None, q_offset=0,
                slot_positions=None, slot_done=None, plens=None,
-               chunk_offsets=None):
+               chunk_offsets=None, live=None):
     """Scan a stacked block group. caches: stacked (n, ...) or None.
+    Returns (x, aux loss, MoE stats summed over the group, caches).
 
-    Slot decode over a paged k/v group carries the arenas whole through
-    the scan, viewed as one layer-flattened arena ``(n * n_pages, ...)``,
-    and scans only the block table: each layer scatters its row into the
-    carried arena in place and reads its pages through a per-layer
-    offset table (``_layer_block_table``).  Scanning the arenas as
-    ``xs``/``ys`` would slice out and re-stack every layer's arena on
-    every decode step instead.
+    Slot decode over a paged group (K/V, or MLA's ckv/kr latents)
+    carries the arenas whole through the scan, viewed as one
+    layer-flattened arena ``(n * n_pages, ...)``, and scans only the
+    block table: each layer scatters its row into the carried arena in
+    place and reads its pages through a per-layer offset table
+    (``_layer_block_table``).  Scanning the arenas as ``xs``/``ys``
+    would slice out and re-stack every layer's arena on every decode
+    step instead.
+
+    The dropless expert layer's weights stay whole in the same way:
+    viewed as one layer-flattened expert axis ``(n * held, ...)`` and
+    closed over, layer ``l`` routes its pairs to groups ``l * held`` on
+    of the grouped matmul, which reads only the experts that get pairs.
+    A scanned slice would be copied out for the grouped-matmul kernel,
+    every expert of every layer on every step.
     """
+    n = jax.tree.leaves(group)[0].shape[0]
     arena = None
     if slot_positions is not None and caches is not None \
-            and set(caches) == {"k", "v", "bt"}:
-        n_layers, n_pages = caches["k"].shape[:2]
-        arena = {lk: caches[lk].reshape((n_layers * n_pages,)
+            and "bt" in caches:
+        leaves = [lk for lk in caches if lk != "bt"]
+        n_pages = caches[leaves[0]].shape[1]
+        arena = {lk: caches[lk].reshape((n * n_pages,)
                                         + caches[lk].shape[2:])
-                 for lk in ("k", "v")}
+                 for lk in leaves}
+    experts = None
+    if moe and caches is not None:
+        m = group["moe"]
+        experts = {w: m[w].reshape((-1,) + m[w].shape[2:])
+                   for w in moe_lib.EXPERT_WEIGHTS}
+        group = {**group, "moe": {k: v for k, v in m.items()
+                                  if k not in experts}}
 
     def body(carry, xs):
-        xc, aux_sum, arena_c = carry
-        if caches is None:
-            bp = xs
-            xc, aux, _ = _block(xc, bp, cfg, positions, moe=moe,
-                                q_offset=q_offset, window=cfg.window)
-            return (xc, aux_sum + aux, arena_c), None
-        if arena_c is None:
-            bp, cache_l = xs
-        else:
-            bp, (layer, bt) = xs
+        xc, aux_sum, st_sum, arena_c = carry
+        bp, layer, cache_l = xs
+        if experts is not None:
+            bp = {**bp, "moe": {**bp["moe"], **experts,
+                                "group0": layer * cfg.n_experts_held}}
+        if arena_c is not None:
             cache_l = {**arena_c, "bt": _layer_block_table(
-                bt, layer, n_pages, n_layers)}
-        xc, aux, nc = _block(xc, bp, cfg, positions, moe=moe, cache=cache_l,
-                             q_offset=q_offset, window=cfg.window,
-                             slot_positions=slot_positions,
-                             slot_done=slot_done, plens=plens,
-                             chunk_offsets=chunk_offsets)
+                cache_l, layer, n_pages, n)}
+        xc, aux, st, nc = _block(xc, bp, cfg, positions, moe=moe,
+                                 cache=cache_l, q_offset=q_offset,
+                                 window=cfg.window,
+                                 slot_positions=slot_positions,
+                                 slot_done=slot_done, plens=plens,
+                                 chunk_offsets=chunk_offsets, live=live)
         if arena_c is None:
-            return (xc, aux_sum + aux, None), nc
-        return (xc, aux_sum + aux, {lk: nc[lk] for lk in arena_c}), None
+            return (xc, aux_sum + aux, st_sum + st, None), nc
+        return (xc, aux_sum + aux, st_sum + st,
+                {lk: nc[lk] for lk in arena_c}), None
 
     if cfg.remat == "block":
         body = jax.remat(body, prevent_cse=False)
@@ -795,20 +835,16 @@ def _run_group(x, group, cfg, positions, *, moe, caches=None, q_offset=0,
         body = jax.remat(
             body, prevent_cse=False,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    if caches is None:
-        xs = group
-    elif arena is not None:
-        xs = (group, (jnp.arange(n_layers, dtype=caches["bt"].dtype),
-                      caches["bt"]))
-    else:
-        xs = (group, caches)
-    (x, aux, arena), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32), arena), xs,
+    xs = (group, jnp.arange(n, dtype=jnp.int32),
+          caches["bt"] if arena is not None else caches)
+    (x, aux, stats, arena), new_caches = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.int32),
+               arena), xs,
         unroll=cfg.unroll_scans)
     if arena is not None:
         new_caches = {**caches, **{lk: a.reshape(caches[lk].shape)
                                    for lk, a in arena.items()}}
-    return x, aux, new_caches
+    return x, aux, stats, new_caches
 
 
 # ================================================================== forward
@@ -857,12 +893,12 @@ def forward(params, batch, cfg):
     positions = _positions_from_batch(batch, B, S, cfg)
     aux_total = 0.0
     if "dense_blocks" in params:
-        x, aux, _ = _run_group(x, params["dense_blocks"], cfg, positions,
-                               moe=False)
+        x, aux, _, _ = _run_group(x, params["dense_blocks"], cfg,
+                                  positions, moe=False)
         aux_total += aux
     if "moe_blocks" in params:
-        x, aux, _ = _run_group(x, params["moe_blocks"], cfg, positions,
-                               moe=True)
+        x, aux, _, _ = _run_group(x, params["moe_blocks"], cfg, positions,
+                                  moe=True)
         aux_total += aux
     x = _final_norm(params, x, cfg)
     aux = {"moe_aux": aux_total}
@@ -902,7 +938,7 @@ def _mtp_forward(params, h, batch, positions, cfg):
     z = jnp.einsum("bsd,dD->bsD", jnp.concatenate([hh, ee], -1),
                    mp["proj"].astype(cdt))
     pos = positions[:, :-1] if positions.ndim == 2 else positions[..., :-1]
-    z, _, _ = _run_group(z, mp["block"], cfg, pos, moe=False)
+    z, _, _, _ = _run_group(z, mp["block"], cfg, pos, moe=False)
     return _head(params, z, cfg)
 
 
@@ -948,22 +984,31 @@ def init_cache(cfg, batch_size, max_len, dtype=None):
 
 
 def _forward_cached(params, batch, cfg, cache, q_offset, plens=None):
+    """Prompt or decode chunk through the cache.  With ``plens`` (B,)
+    (bucket-padded admission) the head runs only at each row's last real
+    position: logits (B, V); else at every position: (B, S, V)."""
     x = embed_inputs(params, batch, cfg)
     B, S = x.shape[:2]
     positions = _positions_from_batch(batch, B, S, cfg, q_offset=q_offset)
+    live = None
+    if plens is not None:
+        live = jnp.arange(S)[None, :] < plens[:, None]
     new_cache = {}
     if "dense_blocks" in params:
-        x, _, nc = _run_group(x, params["dense_blocks"], cfg, positions,
-                              moe=False, caches=cache["dense"],
-                              q_offset=q_offset, plens=plens)
+        x, _, _, nc = _run_group(x, params["dense_blocks"], cfg, positions,
+                                 moe=False, caches=cache["dense"],
+                                 q_offset=q_offset, plens=plens)
         new_cache["dense"] = nc
     if "moe_blocks" in params:
-        x, _, nc = _run_group(x, params["moe_blocks"], cfg, positions,
-                              moe=True, caches=cache["moe"],
-                              q_offset=q_offset, plens=plens)
+        x, _, _, nc = _run_group(x, params["moe_blocks"], cfg, positions,
+                                 moe=True, caches=cache["moe"],
+                                 q_offset=q_offset, plens=plens, live=live)
         new_cache["moe"] = nc
+    if plens is not None:
+        x = x[jnp.arange(B), plens - 1][:, None]
     x = _final_norm(params, x, cfg)
-    return _head(params, x, cfg), new_cache
+    logits = _head(params, x, cfg)
+    return (logits[:, 0] if plens is not None else logits), new_cache
 
 
 def prefill(params, batch, cfg, cache):
@@ -989,18 +1034,19 @@ def decode_step(params, tokens, pos, cache, cfg):
     return logits[:, -1], cache
 
 
-def prefill_full(params, batch, cfg, cache):
-    """Prefill returning logits at EVERY prompt position: (B, S, V).
+def prefill_last(params, batch, cfg, cache):
+    """Admission prefill of bucket-padded prompts: logits (B, V) at each
+    row's last real prompt position, and the cache.
 
-    The continuous-batching engine pads prompts to a bucket length to bound
-    prefill recompiles; it reads the logits at each request's true last
-    prompt token, so it needs the whole sequence of logits.  An optional
-    ``batch["plens"]`` (B,) carries each row's TRUE prompt length — ignored
-    by full caches (pad-tail entries hide behind the per-row ``kv_len``
-    mask) but required to fill ring-buffer window caches per row.
+    ``batch["plens"]`` (B,) carries each row's TRUE prompt length: the
+    head runs at ``plens - 1`` only (the whole ``(B, S, V)`` logits of a
+    large vocabulary would not fit beside the weights), ring-buffer
+    window caches fill per row from it, full caches hide the pad tail
+    behind the per-row ``kv_len`` mask, and expert layers compute no
+    routed pairs for pad positions.
     """
-    plens = batch.get("plens")
-    batch = {k: v for k, v in batch.items() if k != "plens"}
+    batch = dict(batch)
+    plens = batch.pop("plens")
     return _forward_cached(params, batch, cfg, cache, q_offset=0,
                            plens=plens)
 
@@ -1013,23 +1059,27 @@ def _forward_cached_slots(params, batch, cfg, cache, slot_positions,
     if cfg.rope == "mrope":
         positions = jnp.broadcast_to(positions[None], (3, B, S))
     new_cache = {}
+    stats = jnp.zeros((2,), jnp.int32)
     if "dense_blocks" in params:
-        x, _, nc = _run_group(x, params["dense_blocks"], cfg, positions,
-                              moe=False, caches=cache["dense"],
-                              slot_positions=slot_positions,
-                              slot_done=slot_done)
+        x, _, _, nc = _run_group(x, params["dense_blocks"], cfg, positions,
+                                 moe=False, caches=cache["dense"],
+                                 slot_positions=slot_positions,
+                                 slot_done=slot_done)
         new_cache["dense"] = nc
     if "moe_blocks" in params:
-        x, _, nc = _run_group(x, params["moe_blocks"], cfg, positions,
-                              moe=True, caches=cache["moe"],
-                              slot_positions=slot_positions,
-                              slot_done=slot_done)
+        live = None if slot_done is None else ~slot_done[:, None]
+        x, _, stats, nc = _run_group(x, params["moe_blocks"], cfg,
+                                     positions, moe=True,
+                                     caches=cache["moe"],
+                                     slot_positions=slot_positions,
+                                     slot_done=slot_done, live=live)
         new_cache["moe"] = nc
     x = _final_norm(params, x, cfg)
-    return _head(params, x, cfg), new_cache
+    return _head(params, x, cfg), new_cache, stats
 
 
-def decode_step_slots(params, tokens, positions, cache, cfg, done=None):
+def decode_step_slots(params, tokens, positions, cache, cfg, done=None,
+                      stats=False):
     """Continuous-batching decode: one token per slot at per-slot lengths.
 
     tokens: (B,) int32 — the last generated token of each slot;
@@ -1038,11 +1088,16 @@ def decode_step_slots(params, tokens, positions, cache, cfg, done=None):
     done: optional (B,) bool — finished/idle rows; they attend with
     ``kv_len == 0`` (the idle-row short-circuit) and their cache write is a
     bit-identical re-store, so the macro-step scan can keep running them as
-    no-ops.  Returns (logits (B, V), new_cache).
+    no-ops.  Returns (logits (B, V), new_cache), and with ``stats`` also
+    the expert layers' (2,) int32 [token-expert pairs computed, held
+    experts hit], summed over layers (zeros for a model without experts;
+    done rows compute no pairs).
     """
     batch = {"tokens": tokens[:, None], "positions": positions[:, None]}
-    logits, cache = _forward_cached_slots(params, batch, cfg, cache,
-                                          positions, slot_done=done)
+    logits, cache, st = _forward_cached_slots(params, batch, cfg, cache,
+                                              positions, slot_done=done)
+    if stats:
+        return logits[:, -1], cache, st
     return logits[:, -1], cache
 
 
@@ -1073,14 +1128,17 @@ def verify_step_slots(params, tokens, positions, cache, cfg, done=None):
         pos = jnp.broadcast_to(pos2d[None], (3, B, S))
     pending = {}
     if "dense_blocks" in params:
-        x, _, pd = _run_group(x, params["dense_blocks"], cfg, pos,
-                              moe=False, caches=cache["dense"],
-                              chunk_offsets=positions, slot_done=done)
+        x, _, _, pd = _run_group(x, params["dense_blocks"], cfg, pos,
+                                 moe=False, caches=cache["dense"],
+                                 chunk_offsets=positions, slot_done=done)
         pending["dense"] = pd
     if "moe_blocks" in params:
-        x, _, pd = _run_group(x, params["moe_blocks"], cfg, pos,
-                              moe=True, caches=cache["moe"],
-                              chunk_offsets=positions, slot_done=done)
+        live = None if done is None else jnp.broadcast_to(
+            ~done[:, None], (B, S))
+        x, _, _, pd = _run_group(x, params["moe_blocks"], cfg, pos,
+                                 moe=True, caches=cache["moe"],
+                                 chunk_offsets=positions, slot_done=done,
+                                 live=live)
         pending["moe"] = pd
     x = _final_norm(params, x, cfg)
     return _head(params, x, cfg), pending

@@ -114,7 +114,8 @@ _WINDOW_COUNTERS = (
     "n_prefix_hits", "n_prefix_misses", "n_prefix_stalls",
     "n_pages_allocated", "n_expired", "n_quarantined", "n_shed",
     "n_spec_fallbacks", "n_faults_injected", "n_degraded_admissions",
-    "n_held_for_upgrade",
+    "n_held_for_upgrade", "n_blocks_read", "n_moe_pairs",
+    "n_moe_experts_hit",
 )
 
 
@@ -364,7 +365,7 @@ class ContinuousBatchingEngine:
     """Slot-pool continuous batching over a family's slot-state protocol.
 
     The engine is family-agnostic: it only talks to ``init_cache`` /
-    ``prefill_full`` / ``decode_step_slots`` (plus ``verify_step_slots``
+    ``prefill_last`` / ``decode_step_slots`` (plus ``verify_step_slots``
     / ``commit_slots`` in speculative mode) and treats the slot pool as
     an opaque pytree whose leaves lead with (layers, capacity, ...).  That
     covers the transformer family's full KV and MLA latent caches,
@@ -445,8 +446,8 @@ class ContinuousBatchingEngine:
         self._oom_waves = 0  # admission waves stalled by an oom fault
         self._poison_jit = None  # lazy donated jit of faults.poison_pool
         self._evict_pending: List[int] = []
-        # (block, valid, [(slot, uid)], stats) of dispatched-but-unread
-        # macro steps
+        # (block, valid, poison, draft fault, [(slot, uid)], spec stats,
+        # moe stats) of dispatched-but-unread macro steps
         self._inflight: collections.deque = collections.deque()
         # live-upgrade machinery: serve/upgrade.py attaches an
         # UpgradeManager here and drives upgrade_state through
@@ -473,6 +474,12 @@ class ContinuousBatchingEngine:
         self.n_faults_injected = 0  # FaultPlan records actually fired
         self.n_degraded_admissions = 0  # full-reservation paged admissions
         self.n_held_for_upgrade = 0  # submits held across a swap window
+        # plain macro blocks read back, and their expert-layer work summed
+        # over steps and layers: token-expert pairs computed here, held
+        # experts that got a pair (zero for a model without experts)
+        self.n_blocks_read = 0
+        self.n_moe_pairs = 0
+        self.n_moe_experts_hit = 0
         # drained-window history (satellite: drain() snapshots + resets
         # the window counters; lifetime totals live here)
         self.lifetime: Dict[str, int] = {c: 0 for c in _WINDOW_COUNTERS}
@@ -1541,6 +1548,7 @@ class ContinuousBatchingEngine:
             tokens, positions, remaining, eos_ids, done, keys = self._state
             stats = None
             dbad = None
+            moe = None
             if self.speculative is not None and not self._spec_fallback:
                 (block, valid, poison, dbad, tokens, positions, remaining,
                  done, pool_t, pool_d, keys, n_prop, n_acc) = self._loop(
@@ -1554,19 +1562,21 @@ class ContinuousBatchingEngine:
                 # serving through the plain macro loop on its TARGET pool
                 loop = self._fb_loop if self._spec_fallback else self._loop
                 if self.sampling is not None:
-                    (block, valid, poison, tokens, positions, remaining, done,
-                     pool, keys) = loop(self.params, tokens, positions,
-                                        remaining, eos_ids, done,
-                                        self._pools[0], keys)
+                    (block, valid, poison, moe, tokens, positions, remaining,
+                     done, pool, keys) = loop(self.params, tokens, positions,
+                                              remaining, eos_ids, done,
+                                              self._pools[0], keys)
                 else:
-                    (block, valid, poison, tokens, positions, remaining, done,
-                     pool) = loop(self.params, tokens, positions, remaining,
-                                  eos_ids, done, self._pools[0])
+                    (block, valid, poison, moe, tokens, positions, remaining,
+                     done, pool) = loop(self.params, tokens, positions,
+                                        remaining, eos_ids, done,
+                                        self._pools[0])
                 self._pools = (pool,) + self._pools[1:]
             self._state = (tokens, positions, remaining, eos_ids, done, keys)
             self.n_decode_dispatches += 1
             live = [(slot, seq.req.uid) for slot, seq in self.active.items()]
-            self._inflight.append((block, valid, poison, dbad, live, stats))
+            self._inflight.append((block, valid, poison, dbad, live, stats,
+                                   moe))
 
     def _process(self, item):
         """Block on one macro step's token block (the single host sync per
@@ -1574,11 +1584,16 @@ class ContinuousBatchingEngine:
         NaN/Inf sentinels and the journal's committed-token deltas ride
         this same readback — fault tolerance adds no host sync."""
         with tracing.span("readback"):
-            block, valid, poison, dbad, live, stats = item
+            block, valid, poison, dbad, live, stats, moe = item
             with tracing.span("readback.wait"):
-                block, valid, poison, dbad, stats = jax.device_get(
-                    (block, valid, poison, dbad, stats))
+                block, valid, poison, dbad, stats, moe = jax.device_get(
+                    (block, valid, poison, dbad, stats, moe))
             self.n_host_syncs += 1
+            if moe is not None:
+                # expert-layer counts ride the same readback
+                self.n_blocks_read += 1
+                self.n_moe_pairs += int(moe[0])
+                self.n_moe_experts_hit += int(moe[1])
             if stats is not None:
                 # acceptance telemetry rides the same readback — no extra
                 # sync

@@ -107,7 +107,7 @@ def make_draft_prefill(cfg_d):
     fam = get_family(cfg_d)
 
     def prefill_fn(params_d, tokens, plens, cache):
-        _, cache = fam.prefill_full(
+        _, cache = fam.prefill_last(
             params_d, {"tokens": tokens, "plens": plens}, cfg_d, cache)
         return cache
 

@@ -42,6 +42,9 @@ SCOPES = (
     "attn.core",      # the attention kernel, or gather plus attend
     "attn.out",       # output projection and residual
     "mlp",            # post-attention norm, MLP or MoE, residual
+    "moe.route",      # in mlp: gate, sorting pairs by held expert
+    "moe.experts",    # in mlp: grouped matmuls of the held experts
+    "moe.shared",     # in mlp: the shared expert
     "head",           # final norm and logits
     "select",         # NaN sentinel, argmax or sample, stopping
 )

@@ -116,21 +116,6 @@ def make_decode_step(cfg):
     return decode_fn
 
 
-def make_prefill_full_step(cfg):
-    """Prefill that returns logits at every position (continuous batching:
-    prompts are padded to bucket lengths, the engine reads each request's
-    true last-token logits)."""
-    fam = get_family(cfg)
-    if not hasattr(fam, "prefill_full"):
-        raise NotImplementedError(
-            f"family {cfg.family!r} has no full-logits prefill")
-
-    def prefill_fn(params, batch, cache):
-        return fam.prefill_full(params, batch, cfg, cache)
-
-    return prefill_fn
-
-
 def make_slot_decode_step(cfg):
     """Continuous-batching decode: every batch row is an independent cache
     slot at its own sequence length.
@@ -159,8 +144,9 @@ def make_prefill_admit_step(cfg, sampling=None):
 
     All requests of one prefill bucket run as ONE multi-row forward; the
     first generated token of each row (argmax at its true last prompt
-    position) is computed on device, so admission costs one dispatch per
-    bucket group instead of one prefill + one host argmax per request.
+    position, the only position the head runs at) is computed on device,
+    so admission costs one dispatch per bucket group instead of one
+    prefill + one host argmax per request.
 
     ``plens`` rides along in the batch: full KV caches ignore it (the
     pad tail hides behind the per-row ``kv_len`` mask), but ring-buffer
@@ -185,15 +171,13 @@ def make_prefill_admit_step(cfg, sampling=None):
     from repro.serve import sampling as sampling_lib
 
     fam = get_family(cfg)
-    if not hasattr(fam, "prefill_full"):
+    if not hasattr(fam, "prefill_last"):
         raise NotImplementedError(
-            f"family {cfg.family!r} has no full-logits prefill")
+            f"family {cfg.family!r} has no admission prefill")
 
     def last_logits(params, tokens, plens, cache):
-        logits, cache = fam.prefill_full(
+        return fam.prefill_last(
             params, {"tokens": tokens, "plens": plens}, cfg, cache)
-        rows = jnp.arange(tokens.shape[0])
-        return logits[rows, plens - 1], cache
 
     if sampling_lib.is_greedy(sampling):
         def prefill_fn(params, tokens, plens, cache):
@@ -228,7 +212,7 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
     fn(params, tokens (B,), positions (B,), remaining (B,), eos_ids (B,),
        done (B,), cache) ->
         (block (K, B) int32, valid (K, B) bool, poison (B,) bool,
-         tokens, positions, remaining, done, cache)
+         moe (2,) int32, tokens, positions, remaining, done, cache)
 
     The host syncs once per K generated tokens instead of once per token:
     eos / max-new-token stopping is applied per slot *inside* the scan.  A
@@ -250,6 +234,10 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
     update can propagate) and its ``poison`` flag rides the block
     readback, so detection costs zero extra host syncs.  The engine
     quarantine-evicts flagged slots.
+
+    ``moe`` sums the expert layers' [token-expert pairs computed, held
+    experts hit] over the block's steps and layers (zeros for a model
+    without experts); it rides the same readback.
 
     ``eos_ids`` uses -1 for "no eos" (token ids are non-negative).
     ``remaining`` counts decode tokens still owed per row; it hits 0
@@ -274,13 +262,21 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
 
     def step(carry, params, eos_ids):
         if greedy:
-            tokens, positions, remaining, done, poison, cache = carry
+            tokens, positions, remaining, done, poison, moe, cache = carry
         else:
-            tokens, positions, remaining, done, poison, cache, keys = carry
+            (tokens, positions, remaining, done, poison, moe, cache,
+             keys) = carry
         live = ~done
-        logits, cache = fam.decode_step_slots(
-            params, tokens, positions, cache, cfg, done=done)
+        if cfg.moe:
+            logits, cache, st = fam.decode_step_slots(
+                params, tokens, positions, cache, cfg, done=done,
+                stats=True)
+        else:
+            logits, cache = fam.decode_step_slots(
+                params, tokens, positions, cache, cfg, done=done)
+            st = 0
         with tracing.scope("select"):
+            moe = moe + st
             # NaN/Inf sentinel: a poisoned live row freezes HERE — its token
             # is never committed and (crucially, for recurrent state) no
             # further update runs on the row.  The elementwise reduce fuses
@@ -300,9 +296,10 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
             done = done | bad | (live & ((tokens == eos_ids)
                                          | (remaining <= 0)))
             positions = jnp.where(live, positions + 1, positions)
-        carry = (tokens, positions, remaining, done, poison, cache) \
+        carry = (tokens, positions, remaining, done, poison, moe, cache) \
             if greedy \
-            else (tokens, positions, remaining, done, poison, cache, keys)
+            else (tokens, positions, remaining, done, poison, moe, cache,
+                  keys)
         return carry, (tokens, live)
 
     if greedy:
@@ -311,10 +308,11 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
             poison0 = jnp.zeros(tokens.shape, bool)
             carry, (block, valid) = jax.lax.scan(
                 lambda c, _: step(c, params, eos_ids),
-                (tokens, positions, remaining, done, poison0, cache),
+                (tokens, positions, remaining, done, poison0,
+                 jnp.zeros((2,), jnp.int32), cache),
                 None, length=k)
-            tokens, positions, remaining, done, poison, cache = carry
-            return (block, valid, poison, tokens, positions, remaining,
+            tokens, positions, remaining, done, poison, moe, cache = carry
+            return (block, valid, poison, moe, tokens, positions, remaining,
                     done, cache)
 
         return loop_fn
@@ -324,11 +322,13 @@ def make_slot_decode_loop(cfg, k: int, sampling=None):
         poison0 = jnp.zeros(tokens.shape, bool)
         carry, (block, valid) = jax.lax.scan(
             lambda c, _: step(c, params, eos_ids),
-            (tokens, positions, remaining, done, poison0, cache, keys),
+            (tokens, positions, remaining, done, poison0,
+             jnp.zeros((2,), jnp.int32), cache, keys),
             None, length=k)
-        tokens, positions, remaining, done, poison, cache, keys = carry
-        return (block, valid, poison, tokens, positions, remaining, done,
-                cache, keys)
+        (tokens, positions, remaining, done, poison, moe, cache,
+         keys) = carry
+        return (block, valid, poison, moe, tokens, positions, remaining,
+                done, cache, keys)
 
     return loop_sampled
 

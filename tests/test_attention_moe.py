@@ -6,7 +6,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.models.attention import attention, reference_attention
-from repro.models.moe import dispatch_combine, moe_mlp, router
+from repro.models import moe as moe_lib
+from repro.models.moe import dispatch_combine
 from repro.configs.base import ModelConfig
 
 
@@ -57,11 +58,16 @@ def test_attention_chunk_invariance(s, chunk, q_offset):
 
 # --------------------------------------------------------------------- MoE
 def test_router_topk_and_aux():
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 8))
-    w = jax.random.normal(jax.random.PRNGKey(1), (8, 4))
+    x = jax.random.normal(jax.random.PRNGKey(0), (32, 8))
+    p = {"w_router": jax.random.normal(jax.random.PRNGKey(1), (8, 4))}
     for score in ("softmax", "sigmoid"):
-        wts, idx, aux = router(x, w, top_k=2, score=score)
-        assert wts.shape == (2, 16, 2) and idx.shape == (2, 16, 2)
+        cfg = ModelConfig(name="r", n_layers=1, d_model=8, n_heads=2,
+                          n_kv_heads=2, d_ff=16, vocab_size=11, moe=True,
+                          n_experts=4, top_k=2, router_score=score)
+        s = moe_lib.router_scores(x, p, cfg)
+        wts, idx = moe_lib.select(s, p, cfg)
+        aux = moe_lib.load_balance_loss(s, idx, cfg)
+        assert wts.shape == (32, 2) and idx.shape == (32, 2)
         np.testing.assert_allclose(np.asarray(wts.sum(-1)), 1.0, atol=1e-5)
         # top-k indices are distinct per token
         assert (np.asarray(idx[..., 0]) != np.asarray(idx[..., 1])).all()
@@ -98,8 +104,6 @@ def test_dispatch_combine_identity_when_unconstrained():
 
 def test_moe_mlp_group_reshape_consistency():
     """Group size must not change results when capacity is ample."""
-    from repro.models import moe as moe_lib
-
     cfg = ModelConfig(name="m", n_layers=1, d_model=16, n_heads=2,
                       n_kv_heads=2, d_ff=32, vocab_size=11, moe=True,
                       n_experts=4, top_k=2, expert_d_ff=32,

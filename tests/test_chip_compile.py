@@ -184,3 +184,39 @@ def test_paged_decode_loop_keeps_arenas_in_place(one_chip, monkeypatch):
         layout = re.search(r"\{[^}]*\}", rtype).group(0)
         assert op == "copy" and layout not in layouts[operand], (
             name, rtype, layouts[operand])
+
+
+def test_expert_layer_reads_held_experts_in_place(one_chip):
+    """The deepseek-v3-ep32 paged decode loop (published widths, 8 of 256
+    experts held, capacity 32, max_len 2560, k 2) runs its expert layers
+    as grouped matmuls over the layer-flattened expert weights: no
+    instruction materializes one layer's held experts (a scanned slice
+    would be copied out for the kernel, every expert on every step)."""
+    cfg = get_config("deepseek-v3-ep32")
+    fam = get_family(cfg)
+
+    def on_chip(shapes):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                            shapes)
+
+    params = on_chip(jax.eval_shape(
+        lambda: fam.init(jax.random.PRNGKey(0), cfg)))
+    cap = 32
+    pool = on_chip(jax.eval_shape(
+        lambda: build_paged_pool(fam, cfg, cap, 2560)[0]))
+    rows = _sds((cap,), jnp.int32, one_chip)
+    done = _sds((cap,), jnp.bool_, one_chip)
+    loop = jax.jit(make_slot_decode_loop(cfg, 2),
+                   donate_argnums=(1, 2, 3, 5, 6))
+    text = loop.lower(params, rows, rows, rows, rows, done,
+                      pool).compile().as_text()
+    assert "ragged-dot" in text
+    H, D, F = cfg.experts_held, cfg.d_model, cfg.expert_d_ff
+    one_layer = {(H, D, F), (H, F, D), (1, H, D, F), (1, H, F, D)}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2)):
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            assert shape not in one_layer, line.strip()[:200]

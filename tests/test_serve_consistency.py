@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, get_config
 from repro.models import get_family
 
 # 8 unjitted decode steps after the prefill: each eager step costs real
@@ -19,10 +19,12 @@ B, S = 2, 16
 PROMPT = 8
 
 
-def _run(cfg, atol=2e-4):
+def _run(cfg, atol=2e-4, tweak=None):
     fam = get_family(cfg)
     rng = jax.random.PRNGKey(0)
     params = fam.init(rng, cfg)
+    if tweak is not None:
+        params = tweak(params)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
                                 cfg.vocab_size)
 
@@ -54,15 +56,35 @@ def test_dense_gqa():
     _run(cfg)
 
 
-@pytest.mark.slow
-def test_moe():
-    cfg = ModelConfig(name="m", n_layers=4, d_model=64, n_heads=4,
-                      n_kv_heads=4, d_ff=128, vocab_size=97, moe=True,
-                      n_experts=4, top_k=2, expert_d_ff=64,
-                      moe_layer_start=2, n_shared_experts=1,
-                      capacity_factor=4.0, attn_chunk=8)
-    # generous capacity so prefill/decode routing drops match
-    _run(cfg, atol=5e-4)
+def _draw_correction_bias(params):
+    m = params["moe_blocks"]["moe"]
+    b = m["e_score_correction_bias"]
+    m["e_score_correction_bias"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(2), b.shape, b.dtype)
+    return params
+
+
+@pytest.mark.parametrize("arch", [
+    pytest.param("generic", marks=pytest.mark.slow),
+    "deepseek-v3-671b-smoke"])
+def test_moe(arch):
+    """The forward's capacity layer with generous capacity drops nothing:
+    it then matches the dropless layer that serving runs, which also
+    checks that both route by one gate.  On the DeepSeek-V3 twin the
+    groups (2 of 4 kept), a drawn correction bias and the routed scale
+    2.5 all change which experts run and their weights (capacity 4 holds
+    every pair: 16 tokens x 2 of 8 experts)."""
+    if arch == "generic":
+        cfg = ModelConfig(name="m", n_layers=4, d_model=64, n_heads=4,
+                          n_kv_heads=4, d_ff=128, vocab_size=97, moe=True,
+                          n_experts=4, top_k=2, expert_d_ff=64,
+                          moe_layer_start=2, n_shared_experts=1,
+                          capacity_factor=4.0, attn_chunk=8)
+        _run(cfg, atol=5e-4)
+        return
+    cfg = get_config(arch).replace(capacity_factor=4.0)
+    assert cfg.router_bias and cfg.n_group > 1 and cfg.routed_scale != 1
+    _run(cfg, tweak=_draw_correction_bias)
 
 
 def test_mla():

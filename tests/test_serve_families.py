@@ -188,7 +188,7 @@ def test_done_rows_freeze_recurrent_state_exactly(family):
                                              seed=7 + i)[0]
                                     for i in range(2)]))
     cache = fam.init_cache(cfg, 2, 16)
-    _, cache = fam.prefill_full(params, {"tokens": prompts,
+    _, cache = fam.prefill_last(params, {"tokens": prompts,
                                          "plens": jnp.asarray([5, 5])},
                                 cfg, cache)  # non-trivial state
     _, nc = fam.decode_step_slots(params, jnp.asarray([1, 2], jnp.int32),
